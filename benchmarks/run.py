@@ -23,6 +23,8 @@ def main() -> None:
     from benchmarks import (common, kv_bench, locality, microbench,
                             pipeline_bench, scheduler_bench, sharded_bench,
                             tilesize, traffic_bench, workloads)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("only", nargs="?", default=None,
                     choices=("microbench", "locality", "workloads",

@@ -8,10 +8,11 @@ interleave (block-sequential DMA / sharded routing) — before touching memory:
   bulk_rmw          A[B[i]] op= C[i]        (IRMW; op in RMW_OPS)
 
 Tables may be 1-D (engine/scalar use) or 2-D row tables (embeddings, KV
-pages, expert buffers). 2-D paths can use the Pallas row-table kernels
-(`use_kernel=True`, default on TPU-shaped inputs); 1-D paths use fused XLA.
-All fall back to reference behaviour under ``optimize=False`` so every paper
-baseline is runnable.
+pages, expert buffers). 2-D paths use the Pallas row-table kernels when the
+caller asks (``use_kernel=True``; off by default), compiled on a TPU and
+interpreted on the CPU; every other path is fused XLA. All fall back to
+reference behaviour under ``optimize=False`` so every paper baseline is
+runnable.
 
 Out-of-range index policy (DESIGN.md §"OOB policy"): **loads clamp, stores
 drop**. ``bulk_gather`` clamps every index into ``[0, n-1)`` — negatives to
@@ -25,12 +26,14 @@ parity-checked, not UB.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import reorder
 from repro.core.isa import alu_apply, rmw_identity
+from repro.kernels.common import tile_shape
 
 _SEG_OPS = {
     "ADD": jax.ops.segment_sum,
@@ -85,25 +88,21 @@ def segment_combine(vals, seg, *, num_segments: int, op: str):
     raise ValueError(f"op {op!r} has no segment reduction (RMW_OPS only)")
 
 
-def _maybe_kernel_gather(table, plan, *, interpret):
-    from repro.kernels.gather import ops as gops
-    return gops.row_table_gather(table, plan, interpret=interpret)
-
-
 # ---------------------------------------------------------------------------
 # gather
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("sort", "dedup", "use_kernel",
-                                   "block_rows", "lanes", "interpret"))
+                                   "block_rows", "lanes"))
 def bulk_gather(table: jax.Array, idx: jax.Array, *, sort: bool = True,
                 dedup: bool = True, use_kernel: bool = False,
-                block_rows: int = 1024, lanes: int = 256,
-                interpret: bool = True) -> jax.Array:
+                block_rows: Optional[int] = None,
+                lanes: Optional[int] = None) -> jax.Array:
     """C = A[B] with reorder+coalesce. Works for (N,) or (N, D) tables.
 
-    use_kernel: route the packed fetch through the Pallas row-table kernel
-    (TPU target; interpret=True executes it on CPU for validation).
+    use_kernel: route the packed fetch of a 2-D table through the Pallas
+    row-table kernel. ``block_rows``/``lanes`` default to the tile
+    ``kernels.common.tile_shape`` derives from the row width and dtype.
     """
     idx = idx.astype(jnp.int32)
     # loads clamp (policy): negatives to row 0, >= n to the last row — on
@@ -116,11 +115,12 @@ def bulk_gather(table: jax.Array, idx: jax.Array, *, sort: bool = True,
     if dedup:
         uniq, inv, _ = reorder.coalesce(flat_idx)
         if use_kernel and table.ndim == 2:
+            from repro.kernels.gather import ops as gops
+            d_rows, d_lanes = tile_shape(table.shape[1], table.dtype)
             plan = reorder.make_row_table_plan(
-                uniq, n_rows=table.shape[0], block_rows=block_rows,
-                lanes=lanes)
-            packed_tiles = _maybe_kernel_gather(table, plan,
-                                                interpret=interpret)
+                uniq, n_rows=table.shape[0],
+                block_rows=block_rows or d_rows, lanes=lanes or d_lanes)
+            packed_tiles = gops.row_table_gather(table, plan)
             # packed_tiles: (num_tiles*lanes, D) in plan order; scatter into
             # sorted-unique order via src_pos, then expand through inverse.
             packed = jnp.zeros((uniq.shape[0],) + table.shape[1:],
@@ -184,13 +184,16 @@ def bulk_scatter(table: jax.Array, idx: jax.Array, values: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("op", "optimize", "use_kernel",
-                                   "block_rows", "lanes", "interpret"))
+                                   "block_rows", "lanes"))
 def bulk_rmw(table: jax.Array, idx: jax.Array, values: jax.Array, *,
              op: str = "ADD", cond: jax.Array | None = None,
              optimize: bool = True, use_kernel: bool = False,
-             block_rows: int = 1024, lanes: int = 256,
-             interpret: bool = True) -> jax.Array:
-    """A[B[i]] op= C[i]; op must be associative+commutative (RMW_OPS)."""
+             block_rows: Optional[int] = None,
+             lanes: Optional[int] = None) -> jax.Array:
+    """A[B[i]] op= C[i]; op must be associative+commutative (RMW_OPS).
+
+    use_kernel / block_rows / lanes: as for ``bulk_gather``, for the final
+    unique scatter of a 2-D table."""
     idx = idx.astype(jnp.int32).reshape(-1)
     if idx.shape[0] == 0:
         return table
@@ -239,8 +242,7 @@ def bulk_rmw(table: jax.Array, idx: jax.Array, values: jax.Array, *,
     if use_kernel and table.ndim == 2:
         from repro.kernels.scatter_rmw import ops as sops
         return sops.row_table_rmw(table, seg_dest.astype(jnp.int32), packed,
-                                  op=op, block_rows=block_rows, lanes=lanes,
-                                  interpret=interpret)
+                                  op=op, block_rows=block_rows, lanes=lanes)
     # (3) unique scatter — every destination written exactly once.
     if op in _BITWISE_OPS:
         # no bitwise scatter mode in XLA: gather-modify-set (dests unique)

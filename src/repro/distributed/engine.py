@@ -58,7 +58,6 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -218,6 +217,9 @@ class ShardedEngine(Engine):
         self._xplan_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
         self.exchange_cost = cost_model or CostModel()
         self.last_shard_stats: Optional[ShardStats] = None
+        # exchange measurements that raised and fell back to the
+        # unmeasured plan (still correct; counted so a smoke can see it)
+        self.stats["exchange_measure_errors"] = 0
 
     # -- static padding to the mesh-divisible shapes shard_map needs --------
     # (table padding/unpadding lives *inside* the jitted graphs so a
@@ -412,6 +414,7 @@ class ShardedEngine(Engine):
                        "rmw", ns)
                 self._seed_cache(key, node.idx, node.cond, meas, perm)
         except Exception:
+            self.stats["exchange_measure_errors"] += 1
             return cost.exchange_plan(None)
         xplan = cost.exchange_plan(meas)
         if xplan.placement == "owner" and perm is None:
@@ -780,12 +783,12 @@ class ShardedEngine(Engine):
                                      cond=cat_valid, optimize=True)
 
         if kind == "gather":
-            route_sm = shard_map(gather_route, mesh=self.mesh,
-                                 in_specs=(sharded, sharded),
-                                 out_specs=(sharded,) * 10)
-            exec_sm = shard_map(gather_exec, mesh=self.mesh,
-                                in_specs=(sharded,) * 9,
-                                out_specs=sharded)
+            route_sm = jax.shard_map(gather_route, mesh=self.mesh,
+                                     in_specs=(sharded, sharded),
+                                     out_specs=(sharded,) * 10)
+            exec_sm = jax.shard_map(gather_exec, mesh=self.mesh,
+                                    in_specs=(sharded,) * 9,
+                                    out_specs=sharded)
 
             def route_fn(idx, mask, perm):
                 return route_sm(idx[perm], mask[perm]) + (mask[perm],)
@@ -807,12 +810,12 @@ class ShardedEngine(Engine):
                               order, slot, r_local, recv_valid, mask2)
                 return out, sent, n_recv, n_uniq
         elif kind == "rmw":
-            route_sm = shard_map(rmw_route, mesh=self.mesh,
-                                 in_specs=(sharded,) * 3,
-                                 out_specs=(sharded,) * 6)
-            exec_sm = shard_map(rmw_exec, mesh=self.mesh,
-                                in_specs=(sharded,) * 4,
-                                out_specs=sharded)
+            route_sm = jax.shard_map(rmw_route, mesh=self.mesh,
+                                     in_specs=(sharded,) * 3,
+                                     out_specs=(sharded,) * 6)
+            exec_sm = jax.shard_map(rmw_exec, mesh=self.mesh,
+                                    in_specs=(sharded,) * 4,
+                                    out_specs=sharded)
 
             def route_fn(idx, mask, vals, perm):
                 return route_sm(idx[perm], mask[perm], vals[perm])

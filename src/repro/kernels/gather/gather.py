@@ -6,12 +6,15 @@ the Row Table: it drives ``BlockSpec.index_map`` so Mosaic issues one
 HBM->VMEM DMA per opened block ("row activate"), and — because Pallas keeps a
 block resident while consecutive grid steps map to the same index — all
 subsequent tiles of that block are served from VMEM ("row-buffer hits").
-Word offsets (the Word Table) index within the open block.
+Word offsets (the Word Table) index within the open block; each tile's
+offsets are a ``(None, 1, lanes)`` block in scalar memory (a ``(1, lanes)``
+VMEM block would break the (8, 128) tiling rule).
 
-VMEM budget per step: block_rows*D + lanes*D + lanes words (double-buffered
-by the pipeline). Choose block_rows*D*dtype <= ~4MB. MXU alignment: D should
-be a multiple of 128, lanes a multiple of 8 (sublane), block_rows a multiple
-of 8.
+VMEM per step: a ``(block_rows, D)`` table block and a ``(lanes, D)`` output
+block, both double-buffered; ``kernels.common.tile_shape`` sizes them.
+16-bit tables read the aligned 16-row group around each row and select the
+row in float32; their output block is float32 and is cast back outside
+the kernel (exact: every value came from the table).
 """
 from __future__ import annotations
 
@@ -22,25 +25,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common
+
 
 def _gather_kernel(tile_block_ref, offs_ref, table_ref, out_ref, *,
-                   lanes: int):
-    """One grid step: serve `lanes` words from the open block."""
-    def body(l, _):
-        # slice starts follow the enabled index width (int64 under x64)
-        off = offs_ref[0, l].astype(jnp.int_)
-        li = jnp.asarray(l, jnp.int_)
-        row = pl.load(table_ref, (pl.dslice(off, 1), slice(None)))
-        pl.store(out_ref, (pl.dslice(li, 1), slice(None)), row)
-        return _
-    jax.lax.fori_loop(0, lanes, body, None)
+                   lanes: int, group: int):
+    """One grid step: serve `lanes` rows from the open block."""
+    def body(l, carry):
+        off = offs_ref[0, l]
+        if group == 1:
+            out_ref[pl.ds(l, 1), :] = table_ref[pl.ds(off, 1), :]
+        else:
+            base = pl.multiple_of((off // group) * group, group)
+            rows = table_ref[pl.ds(base, group), :].astype(jnp.float32)
+            pick = jax.lax.broadcasted_iota(
+                jnp.int32, rows.shape, 0) == off - base
+            out_ref[pl.ds(l, 1), :] = jnp.max(
+                jnp.where(pick, rows, -jnp.inf), axis=0, keepdims=True)
+        return carry
+    jax.lax.fori_loop(0, lanes, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "lanes",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_rows", "lanes"))
 def row_table_gather(table: jax.Array, tile_block: jax.Array,
-                     offsets: jax.Array, *, block_rows: int, lanes: int,
-                     interpret: bool = True) -> jax.Array:
+                     offsets: jax.Array, *, block_rows: int,
+                     lanes: int) -> jax.Array:
     """Gather planned by a row table.
 
     Args:
@@ -54,19 +63,25 @@ def row_table_gather(table: jax.Array, tile_block: jax.Array,
     n, d = table.shape
     assert n % block_rows == 0, (n, block_rows)
     assert offsets.shape == (num_tiles, lanes)
+    group = common.row_group(table.dtype)
+    out_dtype = table.dtype if group == 1 else jnp.float32
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(num_tiles,),
         in_specs=[
-            pl.BlockSpec((1, lanes), lambda i, blk: (i, 0)),
+            pl.BlockSpec((None, 1, lanes), lambda i, blk: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, d), lambda i, blk: (blk[i], 0)),
         ],
         out_specs=pl.BlockSpec((lanes, d), lambda i, blk: (i, 0)),
     )
-    return pl.pallas_call(
-        functools.partial(_gather_kernel, lanes=lanes),
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, lanes=lanes, group=group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_tiles * lanes, d), table.dtype),
-        interpret=interpret,
-    )(tile_block, offsets, table)
+        out_shape=jax.ShapeDtypeStruct((num_tiles * lanes, d), out_dtype),
+        interpret=common.interpret(),
+        name="row_table_gather",
+    )(tile_block.astype(jnp.int32),
+      offsets.astype(jnp.int32).reshape(num_tiles, 1, lanes), table)
+    return out.astype(table.dtype)

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.reorder import RowTablePlan
+from repro.kernels.common import check_tile
 from repro.kernels.gather import gather as _k
 from repro.kernels.gather import ref as _ref
 
@@ -18,7 +19,6 @@ def _pad_table(table: jax.Array, block_rows: int) -> jax.Array:
 
 
 def row_table_gather(table: jax.Array, plan: RowTablePlan, *,
-                     interpret: bool = True,
                      use_ref: bool = False) -> jax.Array:
     """Execute a planned gather. Returns (num_tiles*lanes, D) packed rows."""
     table = _pad_table(table, plan.block_rows)
@@ -26,6 +26,7 @@ def row_table_gather(table: jax.Array, plan: RowTablePlan, *,
         return _ref.row_table_gather_ref(
             table, plan.tile_block, plan.offsets,
             block_rows=plan.block_rows, lanes=plan.lanes)
+    check_tile(plan.block_rows, plan.lanes, table.dtype)
     return _k.row_table_gather(
         table, plan.tile_block, plan.offsets,
-        block_rows=plan.block_rows, lanes=plan.lanes, interpret=interpret)
+        block_rows=plan.block_rows, lanes=plan.lanes)

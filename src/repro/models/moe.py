@@ -124,7 +124,6 @@ def moe_ffn_ep(p: dict, x: jax.Array, *, n_experts: int, top_k: int,
     Requires n_experts == model-axis size and T % data-axis == 0; callers
     fall back to `moe_ffn` otherwise.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.sharding.get_abstract_mesh()
@@ -168,7 +167,7 @@ def moe_ffn_ep(p: dict, x: jax.Array, *, n_experts: int, top_k: int,
         return out.astype(xt.dtype), logits
 
     dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
-    out, logits = shard_map(
+    out, logits = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp_spec, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -278,6 +278,27 @@ class TestAccessServiceMesh:
                                       [5.0, 9.0, 5.0])
         assert svc.last_report.shard_stats
 
+    def test_failed_exchange_measurement_is_counted(self, monkeypatch):
+        # the shard pass measures first; only that measurement fails
+        measure, calls = ShardedEngine._measure_padded, []
+
+        def fails_once(self, *a, **k):
+            calls.append(1)
+            if len(calls) == 1:
+                raise RuntimeError("injected measurement failure")
+            return measure(self, *a, **k)
+        monkeypatch.setattr(ShardedEngine, "_measure_padded", fails_once)
+        svc = AccessService(mesh=MESH_SIZES[-1], tile_size=256,
+                            auto_flush=0)
+        table = jnp.arange(64.0)
+        tickets = [svc.connect(f"c{k}").submit_gather(
+            table, jnp.asarray([k, 9, 5], jnp.int32)) for k in range(2)]
+        svc.flush()
+        for k, t in enumerate(tickets):     # the unmeasured plan is correct
+            np.testing.assert_array_equal(np.asarray(svc.wait(t)),
+                                          [k, 9.0, 5.0])
+        assert svc.stats()["engine"]["exchange_measure_errors"] > 0
+
     def test_mesh_plus_scheduler_rejected(self):
         with pytest.raises(ValueError, match="not both"):
             AccessService(scheduler=Scheduler(), mesh=1)

@@ -41,7 +41,7 @@ class TestGatherKernel:
         n_pad = -(-n // br) * br
         plan = make_row_table_plan(uniq, n_rows=n_pad, block_rows=br,
                                    lanes=lanes)
-        out_k = gops.row_table_gather(table, plan, interpret=True)
+        out_k = gops.row_table_gather(table, plan)
         out_r = gops.row_table_gather(table, plan, use_ref=True)
         np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
@@ -112,3 +112,41 @@ class TestScatterRmwKernel:
                                    block_rows=128, lanes=64, use_ref=True)
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                    rtol=1e-6)
+
+
+class TestDerivedSettings:
+    """Mode, tile and dtype come from the platform, the width and the
+    dtype (``kernels.common``), never from the caller."""
+
+    def test_interpreted_on_cpu_refused_elsewhere(self, monkeypatch):
+        import jax
+        from repro.kernels import common
+        assert common.interpret() is True
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="neither"):
+            common.interpret()
+
+    @pytest.mark.parametrize("dtype", [jnp.float16, jnp.int8, jnp.int16])
+    def test_unsupported_dtype_refused_before_the_kernel(self, rng, dtype):
+        table = jnp.zeros((64, 128), dtype)
+        idx = jnp.asarray([1, 5, 9], jnp.int32)
+        with pytest.raises(ValueError, match="row-table kernels take"):
+            bulk_gather(table, idx, use_kernel=True)
+        with pytest.raises(ValueError, match="row-table kernels take"):
+            sops.row_table_rmw(table, idx, jnp.ones((3, 128), dtype))
+
+    @pytest.mark.parametrize("d", [128, 256, 576, 6144, 16384])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_tile_fits_vmem_budget(self, d, dtype):
+        from repro.kernels import common
+        block_rows, lanes = common.tile_shape(d, dtype)
+        row = d * jnp.dtype(dtype).itemsize
+        assert block_rows & (block_rows - 1) == 0 and lanes & (lanes - 1) == 0
+        assert block_rows % common.row_group(dtype) == 0 and lanes % 8 == 0
+        # RMW: table block in + out, update block, each double-buffered
+        assert 2 * (2 * block_rows * row + lanes * d * 4) <= 16 << 20
+
+    def test_too_wide_rows_refused(self):
+        from repro.kernels import common
+        with pytest.raises(ValueError, match="too wide"):
+            common.tile_shape(1 << 17, jnp.float32)

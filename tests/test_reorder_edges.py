@@ -167,8 +167,7 @@ class TestRowTablePlanEdges:
         idx = np.sort(rng.integers(60, 70, size=12)).astype(np.int32)
         plan = make_row_table_plan(jnp.asarray(idx), n_rows=70,
                                    block_rows=32, lanes=4)
-        packed = gops.row_table_gather(jnp.asarray(table), plan,
-                                       interpret=True)
+        packed = gops.row_table_gather(jnp.asarray(table), plan)
         got = np.asarray(packed)[np.asarray(plan.valid).reshape(-1)]
         np.testing.assert_allclose(got, table[idx], rtol=1e-6)
 
