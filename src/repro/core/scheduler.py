@@ -42,11 +42,13 @@ import dataclasses
 import os
 import weakref
 from collections import OrderedDict
+from functools import partial
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.analysis import hazards as analysis_hazards
 from repro.analysis.diagnostics import HazardError
@@ -57,6 +59,7 @@ from repro.plan import emit as plan_emit
 from repro.plan import nodes as plan_nodes
 from repro.plan import passes as plan_passes
 from repro.plan.explain import Explanation
+from repro.plan.spans import to_device, to_host, uploading
 
 # lowering-decision cache entries kept per scheduler (LRU)
 PLAN_CACHE_SIZE = 256
@@ -395,6 +398,7 @@ class Scheduler:
             self._tenant_pending.get(tenant, 0) + 1
         return None
 
+    @partial(annotate_function, name="dx.submit")
     def submit(self, program: isa.AccessProgram, env: Mapping,
                regs: Mapping | None = None, *,
                tenant: str = "core0") -> Ticket:
@@ -422,6 +426,7 @@ class Scheduler:
         self._queue.append(leaf)
         return leaf.ticket
 
+    @partial(annotate_function, name="dx.submit")
     def submit_gather(self, table, idx, *, tenant: str = "core0") -> Ticket:
         """Bulk fast-path: C = table[idx] with *cross-request* coalescing.
 
@@ -433,11 +438,11 @@ class Scheduler:
         rejected = self._admit(tenant)
         if rejected is not None:
             return rejected
-        jtable = jnp.asarray(table)
+        jtable = to_device(table, "submit")
         # flatten up front: the coalesced fetch always worked on the flat
         # stream (coalesce_streams reshapes), so the eager backend must
         # see the same shape — one canonical form for every path
-        jidx = jnp.asarray(idx).astype(jnp.int32).reshape(-1)
+        jidx = to_device(idx, "submit").astype(jnp.int32).reshape(-1)
         leaf = plan_nodes.GatherNode(
             nid=-1, ticket=self._ticket(tenant), table=jtable, idx=jidx,
             table_id=id(table), table_ref=table,
@@ -445,6 +450,7 @@ class Scheduler:
         self._gather_queue.append(leaf)
         return leaf.ticket
 
+    @partial(annotate_function, name="dx.submit")
     def submit_rmw(self, table, idx, values, *, op: str = "ADD",
                    cond=None, tenant: str = "core0") -> Ticket:
         """Bulk RMW fast-path: ``table[idx] op= values`` with cross-request
@@ -466,12 +472,13 @@ class Scheduler:
         rejected = self._admit(tenant)
         if rejected is not None:
             return rejected
-        jtable = jnp.asarray(table)
-        jidx = jnp.asarray(idx).astype(jnp.int32).reshape(-1)
+        jtable = to_device(table, "submit")
+        jidx = to_device(idx, "submit").astype(jnp.int32).reshape(-1)
         leaf = plan_nodes.RmwNode(
             nid=-1, ticket=self._ticket(tenant), table=jtable, idx=jidx,
-            values=jnp.asarray(values), op=op,
-            cond=None if cond is None else jnp.asarray(cond).reshape(-1),
+            values=to_device(values, "submit"), op=op,
+            cond=None if cond is None
+            else to_device(cond, "submit").reshape(-1),
             table_id=id(table), table_ref=table,
             n_lanes=int(jidx.shape[0]), table_rows=int(jtable.shape[0]))
         self._rmw_queue.append(leaf)
@@ -614,7 +621,8 @@ class Scheduler:
         plan.cache_hit = skeleton is not None
         # hazard scan rides the cached lowering: explain() and the flush
         # see one scan, and it is O(leaves) by design (analysis.hazards)
-        plan.diagnostics = analysis_hazards.scan_window(plan.leaves)
+        with TraceAnnotation("dx.flush.hazard_scan"):
+            plan.diagnostics = analysis_hazards.scan_window(plan.leaves)
         if leaves and skeleton is None:
             self._plan_cache[signature] = plan_passes.skeleton_of(plan)
             while len(self._plan_cache) > PLAN_CACHE_SIZE:
@@ -645,6 +653,7 @@ class Scheduler:
         return self.flush_async(inflight_ok=inflight_ok,
                                 drain_limit=drain_limit).result()
 
+    @partial(annotate_function, name="dx.flush")
     def flush_async(self, *, inflight_ok: bool = False,
                     drain_limit: Optional[int] = None) -> FlushHandle:
         """Drain the queues: lower to a plan, emit every node, retire.
@@ -677,7 +686,8 @@ class Scheduler:
                 "windows deliberately (what repro.pipeline.DecoupledLoop "
                 "does)")
         try:
-            plan = self._lower_pending(drain_limit)
+            with TraceAnnotation("dx.flush.lower"):
+                plan = self._lower_pending(drain_limit)
         except Exception as e:
             # last resort: per-leaf/per-node isolation lives in the
             # passes, but an unforeseen lowering failure must still fail
@@ -739,47 +749,48 @@ class Scheduler:
             make_group_error=lambda node, e: GroupReport(
                 len(node.members), node.members[0].program.name,
                 vmapped=False, fell_back=False, error=repr(e)))
-        plan_emit.execute(plan, ctx, plan_emit.backend_for(self.engine))
+        with TraceAnnotation("dx.flush.emit"):
+            plan_emit.execute(plan, ctx, plan_emit.backend_for(self.engine))
+        with TraceAnnotation("dx.flush.report"):
+            counts = plan.counts()
+            self.stats["flushes"] += 1
+            self.stats["programs"] += counts["programs"]
+            self.stats["gathers"] += counts["gathers"]
+            self.stats["rmws"] += counts["rmws"]
+            for d in plan.diagnostics:
+                bucket = ("hazard_errors" if d.severity == "ERROR"
+                          else "hazard_warnings")
+                self.stats[bucket] += 1
+                for tenant in d.tenants:
+                    per = self.stats["hazards_by_tenant"].setdefault(
+                        tenant, {"errors": 0, "warnings": 0})
+                    per["errors" if d.severity == "ERROR"
+                        else "warnings"] += 1
 
-        counts = plan.counts()
-        self.stats["flushes"] += 1
-        self.stats["programs"] += counts["programs"]
-        self.stats["gathers"] += counts["gathers"]
-        self.stats["rmws"] += counts["rmws"]
-        for d in plan.diagnostics:
-            bucket = ("hazard_errors" if d.severity == "ERROR"
-                      else "hazard_warnings")
-            self.stats[bucket] += 1
-            for tenant in d.tenants:
-                per = self.stats["hazards_by_tenant"].setdefault(
-                    tenant, {"errors": 0, "warnings": 0})
-                per["errors" if d.severity == "ERROR"
-                    else "warnings"] += 1
-
-        gather_streams = {g.table_id: tuple(g.streams)
-                          for g in plan.fused("gather")}
-        rmw_streams = {(r.table_id, r.op): tuple(m.idx for m in r.members)
-                       for r in plan.fused("rmw")}
-        report = FlushReport(
-            order=plan.order,
-            groups=tuple(ctx.group_reports),
-            n_programs=counts["programs"],
-            n_gathers=counts["gathers"],
-            shard_stats=ctx.shard_stats,
-            n_rmws=counts["rmws"],
-            plan=plan,
-            diagnostics=plan.diagnostics,
-            _gather_thunk=(lambda s=gather_streams: {
-                k: reorder.cross_stream_gain(v) for k, v in s.items()}),
-            _rmw_thunk=(lambda s=rmw_streams: {
-                k: reorder.cross_stream_gain(v) for k, v in s.items()}))
-        leaves = jax.tree_util.tree_leaves(
-            [v for v in (self._results.get(tid) for _, tid in plan.order)
-             if v is not None and not isinstance(v, FailedResult)])
-        plan.strip()   # release array payloads; structure stays readable
-        handle = FlushHandle(report, tuple(leaves))
-        self._inflight = weakref.ref(handle)
-        return handle
+            gather_streams = {g.table_id: tuple(g.streams)
+                              for g in plan.fused("gather")}
+            rmw_streams = {(r.table_id, r.op): tuple(m.idx for m in r.members)
+                           for r in plan.fused("rmw")}
+            report = FlushReport(
+                order=plan.order,
+                groups=tuple(ctx.group_reports),
+                n_programs=counts["programs"],
+                n_gathers=counts["gathers"],
+                shard_stats=ctx.shard_stats,
+                n_rmws=counts["rmws"],
+                plan=plan,
+                diagnostics=plan.diagnostics,
+                _gather_thunk=(lambda s=gather_streams: {
+                    k: reorder.cross_stream_gain(v) for k, v in s.items()}),
+                _rmw_thunk=(lambda s=rmw_streams: {
+                    k: reorder.cross_stream_gain(v) for k, v in s.items()}))
+            leaves = jax.tree_util.tree_leaves(
+                [v for v in (self._results.get(tid) for _, tid in plan.order)
+                 if v is not None and not isinstance(v, FailedResult)])
+            plan.strip()   # release array payloads; structure stays readable
+            handle = FlushHandle(report, tuple(leaves))
+            self._inflight = weakref.ref(handle)
+            return handle
 
     # -- emitters (registered on the "local" backend) ------------------------
     # Thin by contract: every fusion/grouping/backend decision was made by
@@ -835,21 +846,22 @@ class Scheduler:
             for m, stream in zip(node.members, node.streams):
                 self._results[m.ticket.tid] = node.table[stream]
             return
-        uniq = np.asarray(node.unique_idx)
+        uniq = to_host(node.unique_idx, "gather_unique")
         cap = _bucket_pow2(uniq.shape[0])
         if cap > uniq.shape[0]:
             # pad the fetch to the bucket with row 0 (in-range, so loads
             # clamp semantics are untouched); inverses never point at pads
             uniq = np.concatenate(
                 [uniq, np.zeros(cap - uniq.shape[0], uniq.dtype)])
-        packed = node.table[uniq]              # single fused fetch
+        with uploading("gather_unique", uniq):
+            packed = node.table[uniq]          # single fused fetch
         for m, inv in zip(node.members, node.inverses):
             self._results[m.ticket.tid] = packed[inv]
 
     def _execute_rmws(self, node: plan_nodes.FusedRmw,
                       ctx: plan_emit.EmitContext) -> None:
         table = ctx.tables.get(node.table_id, node.table)
-        idx = np.asarray(node.idx).reshape(-1)
+        idx = to_host(node.idx, "rmw_idx").reshape(-1)
         vals, cond = node.values, node.cond
         cap = _bucket_pow2(idx.shape[0]) if idx.shape[0] else 0
         if cap > idx.shape[0]:
@@ -857,19 +869,20 @@ class Scheduler:
             # store policy (stores drop) discards them on every path, so
             # padded lanes are no-ops regardless of value
             pad = cap - idx.shape[0]
-            vals = np.asarray(vals).reshape((idx.shape[0],) +
-                                            np.shape(table)[1:])
+            vals = to_host(vals, "rmw_values").reshape(
+                (idx.shape[0],) + np.shape(table)[1:])
             idx = np.concatenate(
                 [idx, np.full(pad, np.shape(table)[0], idx.dtype)])
             vals = np.concatenate(
                 [vals, np.zeros((pad,) + vals.shape[1:], vals.dtype)])
             if cond is not None:
                 cond = np.concatenate(
-                    [np.asarray(cond).reshape(-1).astype(bool),
+                    [to_host(cond, "rmw_values").reshape(-1).astype(bool),
                      np.zeros(pad, bool)])
-        new = bulk_ops.bulk_rmw(table, idx, vals, op=node.op,
-                                cond=cond,
-                                optimize=self.engine.optimize)
+        with uploading("rmw", idx, vals, cond):
+            new = bulk_ops.bulk_rmw(table, idx, vals, op=node.op,
+                                    cond=cond,
+                                    optimize=self.engine.optimize)
         ctx.tables[node.table_id] = new
         ctx.rmw_members.setdefault(node.table_id, []).extend(node.members)
 

@@ -67,6 +67,7 @@ from repro.core.engine import Engine
 from repro.distributed import exchange
 from repro.distributed.mesh import as_mesh
 from repro.plan.cost import CostModel, ExchangePlan
+from repro.plan.spans import to_device, to_host
 
 
 class ShardStats:
@@ -261,9 +262,9 @@ class ShardedEngine(Engine):
             if a is not None and hasattr(a, "is_ready") and \
                     not a.is_ready():
                 return None, None
-        h = np.asarray(idx).reshape(-1).astype(np.int64)
+        h = to_host(idx, "exchange_plan").reshape(-1).astype(np.int64)
         hv = np.ones(n, bool) if valid is None else \
-            np.asarray(valid).reshape(-1).astype(bool)
+            to_host(valid, "exchange_plan").reshape(-1).astype(bool)
         if kind == "gather":
             h = np.clip(h, 0, n_rows - 1)          # loads clamp
         else:
@@ -375,7 +376,8 @@ class ShardedEngine(Engine):
                     if hasattr(s, "is_ready") and not s.is_ready():
                         return cost.exchange_plan(None)
                 cat = np.concatenate(
-                    [np.asarray(s).reshape(-1) for s in node.streams])
+                    [s.reshape(-1) for s in
+                     to_host(list(node.streams), "exchange_plan")])
                 u = np.unique(np.clip(cat.astype(np.int64), 0,
                                       node.table_rows - 1))
                 # replicate the coalesce pass's padded layout: sorted
@@ -401,11 +403,12 @@ class ShardedEngine(Engine):
                     if hasattr(a, "is_ready") and not a.is_ready():
                         return cost.exchange_plan(None)
                 h = np.concatenate(
-                    [np.asarray(a).reshape(-1)
-                     for a in arrs]).astype(np.int64)
+                    [a.reshape(-1) for a in to_host(arrs, "exchange_plan")]
+                ).astype(np.int64)
                 hv = np.concatenate(
                     [np.ones(m.n_lanes, bool) if c is None
-                     else np.asarray(c).reshape(-1).astype(bool)
+                     else to_host(c, "exchange_plan").reshape(-1)
+                     .astype(bool)
                      for m, c in zip(node.members, conds)])
                 hv = hv & (h >= 0) & (h < node.table_rows)
                 meas, perm = self._measure_padded(
@@ -430,7 +433,7 @@ class ShardedEngine(Engine):
         codec = xplan.codec if xplan.capacity else "raw"
         L = per * self.num_shards
         if perm is not None and xplan.placement == "owner":
-            perm_arr = jnp.asarray(perm)
+            perm_arr = to_device(perm, "exchange_perm")
         else:
             perm_arr = jnp.arange(L, dtype=jnp.int32)
         return cap, codec, perm_arr

@@ -33,6 +33,9 @@ import dataclasses
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+from repro.plan.spans import to_host
 
 GATHER_BACKENDS = ("eager", "bulk", "sharded")
 RMW_BACKENDS = ("bulk", "sharded")
@@ -202,15 +205,25 @@ class CostModel:
         """Host-side coalescing factor (#lanes / #distinct rows) of the
         fused stream — only when every stream is already resident (a
         stream still in flight behind JAX async dispatch must not be
-        forced: measurement may never block the flush hot path)."""
-        if node.n_lanes == 0 or node.n_lanes > self.measure_limit:
-            return None
-        for s in node.streams:
-            if hasattr(s, "is_ready") and not s.is_ready():
+        forced: measurement may never block the flush hot path). The
+        span ``dx.cost.measure`` records the ``outcome``: ``measured``,
+        ``in_flight``, ``over_budget`` or ``empty``."""
+        if node.n_lanes == 0:
+            outcome = "empty"
+        elif node.n_lanes > self.measure_limit:
+            outcome = "over_budget"
+        elif any(hasattr(s, "is_ready") and not s.is_ready()
+                 for s in node.streams):
+            outcome = "in_flight"
+        else:
+            outcome = "measured"
+        with TraceAnnotation("dx.cost.measure", outcome=outcome):
+            if outcome != "measured":
                 return None
-        cat = np.concatenate(
-            [np.asarray(s).reshape(-1) for s in node.streams])
-        return float(cat.shape[0] / max(np.unique(cat).shape[0], 1))
+            cat = np.concatenate(
+                [s.reshape(-1)
+                 for s in to_host(list(node.streams), "measure_factor")])
+            return float(cat.shape[0] / max(np.unique(cat).shape[0], 1))
 
     # -- RMWs ----------------------------------------------------------------
 

@@ -21,6 +21,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.plan import nodes, passes
 
 
@@ -121,7 +123,8 @@ def execute(plan: nodes.Plan, ctx: EmitContext, backend: Backend):
             if pf is None:
                 continue
             try:
-                pf(node, ctx)
+                with TraceAnnotation(f"dx.prefetch.{inner.kind}"):
+                    pf(node, ctx)
             except Exception:
                 ctx.exchange_inflight.pop(node.nid, None)
                 ctx.stats["prefetch_errors"] = \
@@ -141,7 +144,8 @@ def execute(plan: nodes.Plan, ctx: EmitContext, backend: Backend):
                 f"in backend {backend.name!r}"), ctx)
             continue
         try:
-            fn(node, ctx)
+            with TraceAnnotation(f"dx.emit.{inner.kind}.{inner.backend}"):
+                fn(node, ctx)
         except Exception as e:          # per-node error isolation
             _fail_node(node, inner, e, ctx)
 

@@ -32,6 +32,8 @@ import dataclasses
 from collections import OrderedDict
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.plan import nodes
 
 PIPELINE = ("normalize", "group", "fuse", "coalesce", "shard", "batch")
@@ -357,7 +359,8 @@ def lower(leaves, order, ctx: LowerContext, backend) -> nodes.Plan:
     if ctx.verify:
         from repro.analysis import verify as _verify
     for name in PIPELINE:
-        plan = backend.passes[name](plan, ctx)
+        with TraceAnnotation(f"dx.pass.{name}"):
+            plan = backend.passes[name](plan, ctx)
         if ctx.verify:
             _verify.check_pass(plan, name, ctx)
     return plan

@@ -68,7 +68,6 @@ class Telemetry:
         self._rejects: Dict[str, int] = {}
         self._drops: Dict[str, int] = {}
         self._depths: List[int] = []
-        self._window_spans: List[Tuple[float, float]] = []
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
         self.n_submits = 0
@@ -116,7 +115,6 @@ class Telemetry:
         n = len(order)
         self._depths.append(n if pending_before is None
                             else int(pending_before))
-        self._window_spans.append((float(start), float(end)))
         if n == 0:
             return
         span = float(end) - float(start)
