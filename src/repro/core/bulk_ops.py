@@ -180,8 +180,82 @@ def bulk_scatter(table: jax.Array, idx: jax.Array, values: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# RMW (IRMW): sort-by-destination -> segment-reduce -> unique scatter.
+# RMW (IRMW): sort-by-destination -> combine runs -> write each row once.
 # ---------------------------------------------------------------------------
+
+def rmw_combine(ndim: int, op: str, optimize: bool) -> str:
+    """How ``bulk_rmw`` combines a table of ``ndim`` dimensions:
+
+    ``"scan"``     1-D tables: one sort carries the values, a segmented
+                   scan totals each run, one unique scatter writes the
+                   totals (a TPU scatters and gathers single elements one
+                   by one; this keeps one of the segment path's five
+                   lane-length scatters and gathers)
+    ``"segment"``  2-D row tables: sort, segment-reduce, then one unique
+                   scatter of rows (each lane moves a whole row, so it
+                   vectorises; ``lax.sort`` carries no row payload)
+    ``"scatter"``  the naive baseline (``optimize=False``): XLA's
+                   duplicate-index scatter; bitwise ops have no such mode
+                   and take a combine path either way
+    """
+    if not optimize and op not in _BITWISE_OPS:
+        return "scatter"
+    return "scan" if ndim == 1 else "segment"
+
+
+def _segmented_scan(vals, keys, op: str):
+    """Inclusive scan of ``op`` within each run of equal sorted ``keys``:
+    log2(L) passes, each combining a lane with the one 2^k lanes before it
+    where both hold one key (sorted, so no other run lies between them).
+    (``lax.associative_scan`` over 2^25 lanes crashed XLA's TPU compiler
+    on a v5e; these passes compile in seconds.)"""
+    d = 1
+    while d < vals.shape[0]:
+        vals = jnp.concatenate([vals[:d], jnp.where(
+            keys[d:] == keys[:-d], alu_apply(op, vals[:-d], vals[d:]),
+            vals[d:])])
+        # one pass at a time: otherwise XLA keeps a third lane-length
+        # buffer live (537 against 404 MB of scratch at 2^25 lanes, v5e)
+        vals, keys = jax.lax.optimization_barrier((vals, keys))
+        d *= 2
+    return vals
+
+
+def _scan_rmw(table, idx, values, op: str):
+    """``table[idx] op= values`` for a 1-D table; ``idx`` already routes
+    dropped lanes past the end.
+
+    One sort carries the values; a segmented inclusive scan leaves each
+    run's total on its last lane, combining only lanes of one run as
+    ``segment_sum`` does (a global prefix differenced at run boundaries
+    would stop being exact once a float32 prefix passes 2^24); one unique
+    scatter writes the run totals, every other lane routed past the end."""
+    sidx, svals = jax.lax.sort((idx, values), num_keys=1)
+    runs = _segmented_scan(svals, sidx, op)
+    last = jnp.concatenate([sidx[1:] != sidx[:-1], jnp.ones((1,), bool)])
+    return _write_unique(table, jnp.where(last, sidx, table.shape[0]),
+                         runs, op)
+
+
+def _write_unique(table, dest, packed, op: str):
+    """``table[dest] op= packed`` where no two in-range ``dest`` are equal;
+    out-of-range destinations drop."""
+    if op in _BITWISE_OPS:
+        # no bitwise scatter mode in XLA: gather-modify-set (dests unique)
+        cur = table[jnp.clip(dest, 0, table.shape[0] - 1)]
+        new = alu_apply(op, cur, packed)
+        return table.at[dest].set(new, mode="drop", unique_indices=True)
+    if op == "ADD":
+        return table.at[dest].add(packed, mode="drop", unique_indices=True)
+    if op == "MAX":
+        return table.at[dest].max(packed, mode="drop", unique_indices=True)
+    if op == "MIN":
+        return table.at[dest].min(packed, mode="drop", unique_indices=True)
+    if op == "MUL":
+        return table.at[dest].multiply(packed, mode="drop",
+                                       unique_indices=True)
+    raise ValueError(op)
+
 
 @partial(jax.jit, static_argnames=("op", "optimize", "use_kernel",
                                    "block_rows", "lanes"))
@@ -197,6 +271,9 @@ def bulk_rmw(table: jax.Array, idx: jax.Array, values: jax.Array, *,
     idx = idx.astype(jnp.int32).reshape(-1)
     if idx.shape[0] == 0:
         return table
+    if op in _BITWISE_OPS and not jnp.issubdtype(table.dtype, jnp.integer):
+        raise ValueError(f"bitwise RMW {op} requires an integer table, "
+                         f"got {table.dtype}")
     values = values.reshape((idx.shape[0],) + table.shape[1:])
     ident = rmw_identity(op, table.dtype)
     # stores drop (policy): route negative/OOB destinations past the end so
@@ -207,7 +284,8 @@ def bulk_rmw(table: jax.Array, idx: jax.Array, values: jax.Array, *,
         cond = cond.reshape(-1)
         cshape = (-1,) + (1,) * (values.ndim - 1)
         values = jnp.where(cond.reshape(cshape), values, ident)
-    if not optimize and op not in _BITWISE_OPS:
+    path = rmw_combine(table.ndim, op, optimize)
+    if path == "scatter":
         # naive baseline: XLA scatter with duplicate indices (serialized on
         # real hardware; the paper's RMW-Atomic analogue).
         if op == "ADD":
@@ -220,7 +298,9 @@ def bulk_rmw(table: jax.Array, idx: jax.Array, values: jax.Array, *,
             return table.at[idx].multiply(values, mode="drop")
         raise ValueError(op)
     # Bitwise ops have no XLA scatter mode, so both optimize settings take
-    # the segment path below — exact either way (associative + commutative).
+    # a combine path below — exact either way (associative + commutative).
+    if path == "scan":
+        return _scan_rmw(table, idx, values, op)
 
     # (1) reorder: sort by destination
     sidx, perm = reorder.sort_indices(idx)
@@ -244,21 +324,4 @@ def bulk_rmw(table: jax.Array, idx: jax.Array, values: jax.Array, *,
         return sops.row_table_rmw(table, seg_dest.astype(jnp.int32), packed,
                                   op=op, block_rows=block_rows, lanes=lanes)
     # (3) unique scatter — every destination written exactly once.
-    if op in _BITWISE_OPS:
-        # no bitwise scatter mode in XLA: gather-modify-set (dests unique)
-        cur = table[jnp.clip(seg_dest, 0, table.shape[0] - 1)]
-        new = alu_apply(op, cur, packed)
-        return table.at[seg_dest].set(new, mode="drop", unique_indices=True)
-    if op == "ADD":
-        return table.at[seg_dest].add(packed, mode="drop",
-                                      unique_indices=True)
-    if op == "MAX":
-        return table.at[seg_dest].max(packed, mode="drop",
-                                      unique_indices=True)
-    if op == "MIN":
-        return table.at[seg_dest].min(packed, mode="drop",
-                                      unique_indices=True)
-    if op == "MUL":
-        return table.at[seg_dest].multiply(packed, mode="drop",
-                                           unique_indices=True)
-    raise ValueError(op)
+    return _write_unique(table, seg_dest, packed, op)

@@ -345,7 +345,7 @@ class Scheduler:
                       "plan_cache_hits": 0, "plan_cache_misses": 0,
                       "rejects": 0, "deferrals": 0,
                       "hazard_errors": 0, "hazard_warnings": 0,
-                      "hazards_by_tenant": {}}
+                      "hazards_by_tenant": {}, "rmw_scan_combines": 0}
 
     # -- submission ----------------------------------------------------------
 
@@ -883,6 +883,11 @@ class Scheduler:
             new = bulk_ops.bulk_rmw(table, idx, vals, op=node.op,
                                     cond=cond,
                                     optimize=self.engine.optimize)
+        combine = bulk_ops.rmw_combine(np.ndim(table), node.op,
+                                       self.engine.optimize)
+        ctx.span.set_metadata(combine=combine)
+        if combine == "scan":
+            self.stats["rmw_scan_combines"] += 1
         ctx.tables[node.table_id] = new
         ctx.rmw_members.setdefault(node.table_id, []).extend(node.members)
 

@@ -103,6 +103,9 @@ class EmitContext:
     # scheduler-provided factories (keeps this module core-type free)
     make_failed: Callable = None           # Exception -> FailedResult
     make_group_error: Callable = None      # (node, Exception) -> report
+    # the open ``dx.emit.*`` span of the node being emitted; its emitter
+    # may add stats to it (``span.set_metadata``)
+    span: object = None
 
 
 def execute(plan: nodes.Plan, ctx: EmitContext, backend: Backend):
@@ -144,7 +147,8 @@ def execute(plan: nodes.Plan, ctx: EmitContext, backend: Backend):
                 f"in backend {backend.name!r}"), ctx)
             continue
         try:
-            with TraceAnnotation(f"dx.emit.{inner.kind}.{inner.backend}"):
+            with TraceAnnotation(
+                    f"dx.emit.{inner.kind}.{inner.backend}") as ctx.span:
                 fn(node, ctx)
         except Exception as e:          # per-node error isolation
             _fail_node(node, inner, e, ctx)

@@ -35,6 +35,77 @@ def oob_stream(rng, n=96, n_rows=N_ROWS):
     return idx
 
 
+UFUNC_AT = {"ADD": np.add, "MAX": np.maximum, "MIN": np.minimum,
+            "MUL": np.multiply, "AND": np.bitwise_and, "OR": np.bitwise_or,
+            "XOR": np.bitwise_xor}
+
+
+def rmw_1d_case(rng, case, dtype, op):
+    """(table, idx, values, cond) of one RMW into a 1-D table. Values are
+    integers (products of +-1 and +-2 under MUL), so float results are
+    exact in any order, but for ``gt_rows`` in float32, whose values are
+    fractional."""
+    lanes = {"lt_rows": N_ROWS // 4, "gt_rows": 4 * N_ROWS,
+             "empty": 0}.get(case, 48)
+    idx = rng.integers(0, N_ROWS, size=lanes).astype(np.int32)
+    cond = None
+    if case == "one_row":
+        idx[:] = 5
+    elif case == "distinct":
+        idx = rng.permutation(N_ROWS)[:lanes].astype(np.int32)
+    elif case == "oob":
+        idx = oob_stream(rng, n=lanes)
+    elif case == "cond":
+        cond = rng.random(lanes) < 0.5
+    if op == "MUL":
+        vals = rng.choice([-2, -1, 1, 2], size=lanes)
+    else:
+        vals = rng.integers(-64, 65, size=lanes)
+    if case == "gt_rows" and np.issubdtype(dtype, np.floating):
+        vals = (rng.uniform(0.5, 2.0, size=lanes) if op == "MUL"
+                else rng.normal(size=lanes))
+    table = rng.integers(-1024, 1024, size=N_ROWS)
+    return table.astype(dtype), idx, vals.astype(dtype), cond
+
+
+def lane_scatters_and_gathers(table_shape, lanes: int):
+    """The gathers and scatters of ``bulk_rmw``'s StableHLO (ADD, float32)
+    whose indices or updates are ``lanes`` long, as (name, operand shapes,
+    whether the indices are declared unique)."""
+    import jax
+    from jaxlib.mlir import ir
+    sds = jax.ShapeDtypeStruct
+    lowered = bulk_rmw.lower(
+        sds(table_shape, jnp.float32), sds((lanes,), jnp.int32),
+        sds((lanes,) + table_shape[1:], jnp.float32), op="ADD")
+    found = []
+
+    def visit(op):
+        if op.name in ("stablehlo.gather", "stablehlo.scatter"):
+            shapes = [list(ir.RankedTensorType(o.type).shape)
+                      for o in op.operands]
+            # gather: (operand, indices); scatter: (operand, indices, updates)
+            if any(s[0] == lanes for s in shapes[1:]):
+                unique = "unique_indices" in op.attributes and \
+                    ir.BoolAttr(op.attributes["unique_indices"]).value
+                found.append((op.name, shapes, unique))
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir("stablehlo").operation.walk(visit)
+    return found
+
+
+def test_rmw_1d_lowers_to_one_unique_lane_scatter():
+    """A 1-D table's RMW moves its lanes through no gather and one scatter,
+    of unique run ends (the segment path had two gathers and three
+    scatters); a 2-D table keeps the unique scatter of whole rows."""
+    lanes, rows = 2 ** 16, 2 ** 12
+    assert lane_scatters_and_gathers((rows,), lanes) == [
+        ("stablehlo.scatter", [[rows], [lanes, 1], [lanes]], True)]
+    assert ("stablehlo.scatter", [[rows, 8], [lanes, 1], [lanes, 8]], True) \
+        in lane_scatters_and_gathers((rows, 8), lanes)
+
+
 # ---------------------------------------------------------------------------
 # bulk-op level: every optimize/kernel path agrees with the policy
 # ---------------------------------------------------------------------------
@@ -86,6 +157,36 @@ class TestBulkOps:
                            jnp.asarray(vals), op=op, optimize=optimize)
             np.testing.assert_array_equal(np.asarray(got), want,
                                           err_msg=f"{op=} {optimize=}")
+
+    @pytest.mark.parametrize("case", ["one_row", "distinct", "oob", "cond",
+                                      "empty", "lt_rows", "gt_rows"])
+    @pytest.mark.parametrize("dtype", [np.float32, np.int32])
+    @pytest.mark.parametrize("op", list(UFUNC_AT))
+    def test_rmw_1d_scan_matches_ufunc_at(self, rng, op, dtype, case):
+        """The 1-D combine (sort, segmented scan, per-row read) against
+        NumPy's unbuffered ``ufunc.at``."""
+        table, idx, vals, cond = rmw_1d_case(rng, case, dtype, op)
+        call = lambda: bulk_rmw(  # noqa: E731
+            jnp.asarray(table), jnp.asarray(idx), jnp.asarray(vals), op=op,
+            cond=None if cond is None else jnp.asarray(cond))
+        if op in ("AND", "OR", "XOR") and dtype is np.float32 \
+                and case != "empty":
+            with pytest.raises(ValueError, match="integer table"):
+                call()
+            return
+        keep = (idx >= 0) & (idx < N_ROWS)
+        if cond is not None:
+            keep &= cond
+        want = table.copy()
+        if keep.any():      # NumPy has no bitwise ops on float32 at all
+            UFUNC_AT[op].at(want, idx[keep], vals[keep])
+        got = np.asarray(call())
+        assert got.dtype == table.dtype
+        if np.issubdtype(dtype, np.floating) and case == "gt_rows":
+            # fractional float sums and products: another rounding order
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(got, want)
 
     def test_rmw_drops_oob_kernel_path_2d(self, rng):
         table = rng.normal(size=(N_ROWS, 4)).astype(np.float32)

@@ -156,6 +156,24 @@ class TestSubmitRmw:
         ((gain, per, fused),) = rep.rmw_coalescing.values()
         assert gain >= 1.0 and fused <= per
 
+    @pytest.mark.parametrize("row_shape,scans", [((), 1), ((4,), 0)])
+    def test_scan_combine_counted_for_1d_tables(self, rng, row_shape, scans):
+        """A fused window of RMWs into a 1-D table takes the scan combine
+        and counts it; a 2-D table's window keeps the row scatter."""
+        sched = Scheduler(engine=Engine(tile_size=TILE))
+        table = np.zeros((32,) + row_shape, np.float32)
+        i1 = rng.integers(0, 32, size=40).astype(np.int32)
+        i2 = rng.integers(0, 32, size=24).astype(np.int32)
+        t1 = sched.submit_rmw(table, i1, np.ones((40,) + row_shape,
+                                                 np.float32), tenant="a")
+        sched.submit_rmw(table, i2, np.ones((24,) + row_shape, np.float32),
+                         tenant="b")
+        sched.flush()
+        assert sched.stats["rmw_scan_combines"] == scans
+        want = np.zeros_like(table)
+        np.add.at(want, np.concatenate([i1, i2]), 1)
+        np.testing.assert_array_equal(np.asarray(sched.result(t1)), want)
+
     def test_different_ops_chain_in_order(self):
         # mixed ops on one table is exactly the DX010 hazard; this test
         # pins the submission-order chaining the scheduler guarantees

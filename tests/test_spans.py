@@ -124,6 +124,25 @@ def test_transfer_bytes(local_window):
     assert by["dx.sync.gather_unique"]["bytes"] > 0
     # the 32-lane RMW needs no padding, so its values stay on the device
     assert "dx.sync.rmw_values" not in by
+    assert by["dx.emit.rmw.bulk"]["combine"] == "segment"   # 2-D table
+
+
+def test_rmw_span_names_scan_combine(tmp_path):
+    """An RMW into a 1-D table marks its emit span ``combine=scan``."""
+    from repro.serve import AccessService
+    svc = AccessService(tile_size=64, auto_flush=0)
+    table = jnp.zeros(64, jnp.float32)
+    idx = np.arange(32, dtype=np.int32) % 8
+
+    def window():
+        t = svc.submit_rmw(table, idx, jnp.ones(32, jnp.float32), op="ADD")
+        svc.flush_async().result()
+        return svc.wait(t)
+
+    window()
+    spans = traced_spans(window, tmp_path)
+    by = {n: st for n, _, _, st in spans}
+    assert by["dx.emit.rmw.bulk"]["combine"] == "scan"
 
 
 def test_padded_rmw_reads_its_values(tmp_path):
