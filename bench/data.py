@@ -61,15 +61,6 @@ HOST_KEYS = ("scrambled_zipfian", "uniform")
 RESIDENT_KEYS = {"nas_is": nas_is_keys}
 
 
-def check_keys(mix: dict) -> None:
-    """ValueError unless a generator makes the mix's keys."""
-    known = RESIDENT_KEYS if mix["keys"] == "resident" else HOST_KEYS
-    dist = mix["key_distribution"]
-    if dist not in known:
-        raise ValueError(f"{mix['keys']} keys cannot follow {dist!r}; "
-                         f"known: {sorted(known)}")
-
-
 def fnv1a64(x: np.ndarray) -> np.ndarray:
     """YCSB's FNV-1a hash of each 64-bit value (ScrambledZipfianGenerator)."""
     x = x.astype(np.uint64)
@@ -123,7 +114,6 @@ def host_pool(cfg: dict, mix: dict, seed: int) -> list:
     gets the same sizes; only the keys and coefficients differ."""
     rng = np.random.default_rng([int(seed), 1])
     rows = cfg["rows"]
-    check_keys(mix)
     if mix["key_distribution"] == "scrambled_zipfian":
         keys = ZipfKeys(rows, mix["zipf_constant"],
                         np.random.default_rng(RANK_SEED),

@@ -1,19 +1,54 @@
 """One benchmark cell, run once, through the access service's public entry.
 
 A cell names a configuration (``bench/configs/<name>.json``: the deployment,
-its table, dtype and flush policy) and a traffic mix
-(``bench/traffic/<name>.json``: clients, request size, operation mix, key
-distribution, loop). Each metric is a reader of its own,
-``bench/metrics/<name>.py``, that reduces the run's record (``Run``) to one
-number or to nothing. The harness knows no cell, mix or metric by name.
+its sizes, dtype and flush policy) and a traffic mix
+(``bench/traffic/<name>.json``: clients, request sizes, operation mix,
+loop). Each metric is a reader of its own, ``bench/metrics/<name>.py``,
+that reduces the run's record (``Run``) to one number or to nothing. The
+configuration's ``kind`` names its deployment kind,
+``bench/kinds/<kind>.py``: what the deployment holds, what a request sends
+and how the result is checked. The harness knows no cell, mix, metric or
+kind by name.
 
 The loop is closed: each client has at most one request outstanding and
 sends its next one as soon as the last one's results are ready on the
-device. A request is one client's bulk submission of ``request_ops`` keys:
-a ``submit_gather`` of the read share and a ``submit_rmw`` ADD of the rest.
-Windows close by the service's own flush policy (``auto_flush`` in the
-configuration); the harness closes one itself only after the measured
+device. Windows close by the service's own flush policy (``auto_flush`` in
+the configuration); the harness closes one itself only after the measured
 window has ended, to drain what is left.
+
+A kind module gives ``check(cfg, mix)``, which raises ValueError where the
+sizes its source states are not what runs, and ``build(cfg, mix, seed,
+chips, devices)``, which makes the deployment on the device from the seed
+and returns an object with:
+
+    data_bytes           bytes of the data it keeps on the device, all chips
+    mesh                 None, or the mesh the service spans
+    request(c)           client ``c``'s next request: an object whose
+                         ``parts`` names its submissions, in order; it may
+                         be formed on the device from the outputs of the
+                         client's last request, with no host read
+    submit(req, part, client)
+                         one submission through the client's handle; the
+                         service's ticket
+    closed(window)       a window closed: ``window`` lists (request, part,
+                         output, or None where its ticket raised) in
+                         submission order; the kind takes its new state
+                         handles and returns its record of the window
+    retired(req, outputs)
+                         the request's outputs are ready (None where a
+                         part failed); the kind keeps what it needs
+    drained()            every request sent has completed
+    ops(req)             operations the request does
+    needed_bytes(windows)
+                         least HBM bytes of these windows' accesses, from
+                         the records ``closed`` returned
+    read_back()          after the window: reads what it compares to the
+                         host, drops its device state, and returns counts
+                         of what it will compare
+    judge(control)       {name: {"value", "limit"}} against its own plain
+                         reference; with ``control`` the reference's
+                         lower-precision stand-in is judged in the
+                         program's place
 """
 from __future__ import annotations
 
@@ -29,10 +64,6 @@ import sys
 import time
 from pathlib import Path
 
-import numpy as np
-
-import data
-import reference
 import roofline
 import trace_reduce
 
@@ -59,6 +90,7 @@ class Cell:
     end_to_end: list
     per_layer: list
     root: Path
+    kind: object                 # the configuration's kind module
 
 
 def _json(path) -> dict:
@@ -78,53 +110,38 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
     conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
     cfg = _json(root / conf["file"])
     mix = _json(root / "bench" / "traffic" / f"{w['traffic']}.json")
-    check_sizes(cfg, mix)
+    kind = find_kind(root, cfg)
+    kind.check(cfg, mix)
     return Cell(
         name=name, chips=int(w["chips"]), cfg=cfg, mix=mix,
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
-        root=root)
+        root=root, kind=kind)
 
 
-def check_sizes(cfg: dict, mix: dict) -> None:
-    """The sizes a configuration states in its source's terms are the sizes
-    the run uses, and the mix's keys have a generator: else ValueError.
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod          # for the dataclasses a module defines
+    spec.loader.exec_module(mod)
+    return mod
 
-    ``recordcount`` (YCSB) and ``max_key`` (NAS IS) are the table's rows;
-    ``fieldcount`` fields of ``fieldlength`` bytes fit in a row; keys
-    resident on the device number ``total_keys``, ``request_ops`` for each
-    client."""
-    rows = cfg["rows"]
-    for key in ("recordcount", "max_key"):
-        if key in cfg and cfg[key] != rows:
-            raise ValueError(f"{key} {cfg[key]} is not the table's {rows} "
-                             "rows")
-    if "fieldcount" in cfg:
-        record = cfg["fieldcount"] * cfg["fieldlength"]
-        room = max(cfg["row_words"], 1) * np.dtype(cfg["dtype"]).itemsize
-        if record > room:
-            raise ValueError(f"a {record}-byte record does not fit a "
-                             f"{room}-byte row")
-    data.check_keys(mix)
-    if mix["keys"] == "resident":
-        keys = mix["clients"] * mix["request_ops"]
-        for key in ("total_keys", "max_key"):
-            if key not in cfg:
-                raise ValueError(f"resident keys need the configuration's "
-                                 f"{key}")
-        if keys != cfg["total_keys"]:
-            raise ValueError(f"the mix's {mix['clients']} clients of "
-                             f"{mix['request_ops']} keys hold {keys} keys, "
-                             f"not the configuration's {cfg['total_keys']}")
+
+def find_kind(root: Path, cfg: dict):
+    """The module of the configuration's ``kind``; ValueError naming the
+    known kinds where it names none of them."""
+    kinds = root / "bench" / "kinds"
+    known = sorted(p.stem for p in kinds.glob("*.py"))
+    name = cfg.get("kind")
+    if name not in known:
+        raise ValueError(f"configuration {cfg.get('name', '')!r} names kind "
+                         f"{name!r}; known kinds: {known}")
+    return _module(kinds / f"{name}.py", f"bench_kind_{name}")
 
 
 def metric_reader(root: Path, name: str):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root / "bench" / "metrics" / f"{name}.py",
+                   f"bench_metric_{name}").read
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +155,7 @@ class Run:
     latencies_s: list            # every request of the window, in seconds
     setup_s: float
     peak_bytes: int              # on the fullest chip
-    data_bytes: int              # table and resident keys, all chips
+    data_bytes: int              # kept on the device, all chips
     chips: int
     spans: list                  # (name, start, end) inside the window
     compiles: int                # backend compiles inside the window
@@ -175,119 +192,27 @@ class CompileCount:
 
 
 # ---------------------------------------------------------------------------
-# the deployment on the device
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class Deployment:
-    table: object
-    pool: list
-    values: list                 # device update values, one per value set
-    col: np.ndarray
-    words: tuple
-    data_bytes: int
-    mesh: object
-
-
-def build(cell: Cell, seed: int, devices) -> Deployment:
-    """Table, keys and update values, made on the device from the seed."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec, \
-        SingleDeviceSharding
-
-    cfg, mix = cell.cfg, cell.mix
-    words = data.seed_words(seed)
-    rows, width = cfg["rows"], cfg["row_words"]
-    shape = (rows, width) if width else (rows,)
-    mesh = None
-    if cell.chips > 1:
-        from repro.distributed.mesh import as_mesh
-        mesh = as_mesh(cell.chips)
-        rowwise = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
-    else:
-        rowwise = SingleDeviceSharding(devices[0])
-    one = SingleDeviceSharding(devices[0])
-
-    def make_table():
-        u = jnp.uint32
-        flat = jax.lax.broadcasted_iota(u, shape, 0) * u(max(width, 1))
-        if width:
-            flat = flat + jax.lax.broadcasted_iota(u, shape, 1)
-        return data.cell_values(flat, words, cfg["value_range"],
-                                jnp).astype(cfg["dtype"])
-    table = jax.jit(make_table, out_shardings=rowwise)()
-    col = data.column_pattern(width, seed)
-    data_bytes = table.nbytes
-
-    if mix["keys"] == "resident":
-        lanes, clients = mix["request_ops"], mix["clients"]
-        kwords = data.seed_words(int(seed) + 1)
-        generate = data.RESIDENT_KEYS[mix["key_distribution"]]
-
-        def make_keys():
-            lane = jax.lax.iota(jnp.uint32, cfg["total_keys"])
-            k = generate(lane, kwords, cfg["max_key"], jnp)
-            return tuple(k[c * lanes:(c + 1) * lanes]
-                         for c in range(clients))
-        keys = jax.jit(make_keys, out_shardings=one)()
-        ones = jax.jit(lambda: jnp.ones((lanes,), cfg["dtype"]),
-                       out_shardings=one)()
-        coef = np.ones(lanes, np.int8)
-        pool = [data.Request(c, c, np.zeros(0, np.int32), keys[c], 0, coef)
-                for c in range(clients)]
-        values = [ones]
-        data_bytes += sum(k.nbytes for k in keys) + ones.nbytes
-    else:
-        pool, sets = data.host_pool(cfg, mix, seed)
-        if sets:
-            stacked = np.stack(sets)
-
-            def make_values(c):
-                v = c.astype(jnp.float32)
-                if width:
-                    v = v[:, :, None] * jnp.asarray(col, jnp.float32)
-                return tuple(v.astype(cfg["dtype"]))
-            # left uncommitted, so that a mesh may place them as it needs
-            values = list(jax.jit(make_values)(stacked))
-        else:
-            values = []
-    return Deployment(table, pool, values, col, words, int(data_bytes), mesh)
-
-
-# ---------------------------------------------------------------------------
 # the closed loop
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
 class Flight:
-    req: data.Request
+    req: object                  # the kind's request
+    client: int
     t_submit: float
-    parts: int
-    version: int = -1            # table version its gather read
     outputs: list = dataclasses.field(default_factory=list)
     dispatched: int = 0
     failed: bool = False
 
 
 class ClosedLoop:
-    def __init__(self, svc, dep: Deployment, mix: dict, spans: Spans,
-                 seed: int):
-        self.svc, self.dep, self.mix, self.spans = svc, dep, mix, spans
-        self.table = dep.table
-        self.clients = [svc.connect(f"client{c}")
-                        for c in range(mix["clients"])]
-        self.by_client = [[r for r in dep.pool if r.client == c]
-                          for c in range(mix["clients"])]
-        self.turn = [0] * mix["clients"]
+    def __init__(self, svc, dep, clients: int, spans: Spans):
+        self.svc, self.dep, self.spans = svc, dep, spans
+        self.clients = [svc.connect(f"client{c}") for c in range(clients)]
         self.pending, self.inflight = [], collections.deque()
-        self.version = 0
-        self.ledger = reference.Ledger(dep.pool)
-        self.windows = []            # (read pool ids, update pool ids)
+        self.windows = []            # the kind's record of each window
         self.flushes = 0
-        self.sample = np.random.default_rng([int(seed), 3])
         self.completed = []          # (flight, done time)
-        self.copying = []            # sampled answers on their way out
         self.error = None
         orig = svc.flush_async
 
@@ -301,25 +226,13 @@ class ClosedLoop:
     # -- submission ----------------------------------------------------------
 
     def submit(self, c: int) -> None:
-        pool = self.by_client[c]
-        req = pool[self.turn[c] % len(pool)]
-        self.turn[c] += 1
-        parts = [k for k, idx in (("read", req.read_idx),
-                                  ("update", req.update_idx))
-                 if int(np.size(idx))]
-        f = Flight(req, time.perf_counter(), len(parts))
-        for kind in parts:
+        req = self.dep.request(c)
+        f = Flight(req, c, time.perf_counter())
+        for part in req.parts:
             before = self.flushes
             with self.spans.span("submit"):
-                if kind == "read":
-                    f.version = self.version
-                    t = self.clients[c].submit_gather(self.table,
-                                                      req.read_idx)
-                else:
-                    t = self.clients[c].submit_rmw(
-                        self.table, req.update_idx,
-                        self.dep.values[req.value_set], op="ADD")
-            self.pending.append((kind, t, f))
+                t = self.dep.submit(req, part, self.clients[c])
+            self.pending.append((part, t, f))
             if self.flushes != before:
                 self._close()
 
@@ -327,25 +240,20 @@ class ClosedLoop:
         """The service flushed: collect the window's results through
         ``wait`` and move its finished requests in flight."""
         window, self.pending = self.pending, []
-        reads, updates, new = [], [], None
-        for kind, t, f in window:
+        done = []
+        for part, t, f in window:
+            out = None
             try:
                 out = self.svc.wait(t)
                 f.outputs.append(out)
-                if kind == "update":
-                    new = out
             except Exception as e:       # a failed plan node's error
                 f.failed = True
                 self.error = self.error or repr(e)
-            (reads if kind == "read" else updates).append(f.req.pool_id)
+            done.append((f.req, part, out))
             f.dispatched += 1
-            if f.dispatched == f.parts:
+            if f.dispatched == len(f.req.parts):
                 self.inflight.append(f)
-        self.windows.append((reads, updates))
-        if updates:
-            self.ledger.versions.append(updates)
-            self.version += 1
-            self.table = new if new is not None else self.table
+        self.windows.append(self.dep.closed(done))
 
     # -- the loop ------------------------------------------------------------
 
@@ -376,23 +284,11 @@ class ClosedLoop:
                 self._retire(f, now)
             for f in done:
                 if not stop(now):
-                    self.submit(f.req.client)
-        self._collect()
-
-    def _collect(self) -> None:
-        for pid, version, out in self.copying:
-            self.ledger.reads.append((pid, version, np.asarray(out)))
-        self.copying = []
+                    self.submit(f.client)
+        self.dep.drained()
 
     def _retire(self, f: Flight, now: float) -> None:
-        # a sampled answer is copied to the host in the background and
-        # collected at the next retirement, so that neither the copy's wait
-        # nor a pile of held device buffers lands in the window
-        self._collect()
-        if (not f.failed and np.size(f.req.read_idx)
-                and self.sample.random() < self.mix["check_share"]):
-            f.outputs[0].copy_to_host_async()
-            self.copying.append((f.req.pool_id, f.version, f.outputs[0]))
+        self.dep.retired(f.req, None if f.failed else f.outputs)
         f.outputs = []               # release the device buffers
         self.completed.append((f, now))
 
@@ -404,24 +300,6 @@ class ClosedLoop:
 def _peak_bytes(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
-
-
-def final_rows(dep: Deployment, cfg: dict, seed: int) -> np.ndarray:
-    """Rows of the final table to compare: all of a small table; else the
-    hottest updated rows and a uniform sample, drawn from the seed."""
-    rows = cfg["rows"]
-    if rows <= cfg["check_rows"]:
-        return np.arange(rows, dtype=np.int32)
-    rng = np.random.default_rng([int(seed), 4])
-    hot = np.zeros(0, np.int32)
-    upd = [np.asarray(r.update_idx) for r in dep.pool
-           if np.size(r.update_idx)]
-    if upd:
-        cat = np.concatenate(upd)
-        u, n = np.unique(cat, return_counts=True)
-        hot = u[np.argsort(-n)[:cfg["check_rows"] // 4]]
-    rest = rng.choice(rows, cfg["check_rows"] - hot.size, replace=False)
-    return np.unique(np.concatenate([hot, rest.astype(np.int32)]))
 
 
 def _use_compile_cache(root: Path) -> None:
@@ -450,11 +328,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              t_start: float, out_dir: Path, control: bool = False,
              chip: bool = True, peaks: dict | None = None) -> dict:
     """Set up, serve for ``seconds``, check against the reference and
-    return the result line (a dict). With ``control`` the bfloat16
-    reference stands in the program's place: ``correct`` and ``checks`` are
-    its verdict and readings, and ``program`` holds the program's own, by
-    the same comparison. ``chip=False`` (tests
-    only) skips the look for a TPU and leaves JAX's compile cache alone."""
+    return the result line (a dict). With ``control`` the kind's
+    lower-precision reference stands in the program's place: ``correct``
+    and ``checks`` are its verdict and readings, and ``program`` holds the
+    program's own, by the same comparison. ``chip=False`` (tests only)
+    skips the look for a TPU and leaves JAX's compile cache alone."""
     import jax.monitoring as monitoring
 
     if chip:    # the TPU runtime's logs stay in the checkout, not in /tmp
@@ -481,19 +359,17 @@ def _run(cell, seed, seconds, trace, t_start, out_dir, control, devices,
     from repro.serve import AccessService
 
     cfg, mix = cell.cfg, cell.mix
-    dep = build(cell, seed, devices)
+    dep = cell.kind.build(cfg, mix, seed, cell.chips, devices)
     svc_cfg = cfg["service"]
     svc = AccessService(tile_size=svc_cfg["tile_size"],
                         auto_flush=svc_cfg["auto_flush"],
                         max_batch=svc_cfg["max_batch"], mesh=dep.mesh)
     spans = Spans()
-    loop = ClosedLoop(svc, dep, mix, spans, seed)
-    rows_to_check = final_rows(dep, cfg, seed)
+    loop = ClosedLoop(svc, dep, mix["clients"], spans)
 
     # warm-up: the cell's own traffic, so exactly its shapes compile
     warm = mix["warm_requests_per_client"] * mix["clients"]
     loop.run(lambda now: len(loop.completed) >= warm)
-    jax.block_until_ready(loop.table)
     warm_done = len(loop.completed)
     # set-up's objects live for the whole run: keep them out of the
     # collector's scans, so that a full collection in the window is short
@@ -519,7 +395,6 @@ def _run(cell, seed, seconds, trace, t_start, out_dir, control, devices,
     setup_s = t0 - t_start
     end = t0 + length
     loop.run(lambda now: now >= end)
-    jax.block_until_ready(loop.table)
     t_drained = time.perf_counter()
     gc.unfreeze()
     window_span.close()
@@ -533,16 +408,10 @@ def _run(cell, seed, seconds, trace, t_start, out_dir, control, devices,
     # last of them completed, so no request is cut in two
     served = loop.completed[warm_done:]
     latencies = [t - f.t_submit for f, t in served]
-    ops = sum(mix["request_ops"] for f, t in served if not f.failed)
+    ops = sum(dep.ops(f.req) for f, t in served if not f.failed)
     span_s = max((t for _, t in served), default=t0) - t0
     failed = sum(1 for f, _ in served if f.failed)
     windows = loop.windows[first_window:]
-    width = cfg["row_words"]
-    row_bytes = max(width, 1) * np.dtype(cfg["dtype"]).itemsize
-
-    def needed():
-        return roofline.needed_bytes(windows, dep.pool, row_bytes,
-                                     row_bytes)
 
     summary = None
     if trace:
@@ -550,18 +419,17 @@ def _run(cell, seed, seconds, trace, t_start, out_dir, control, devices,
     run = Run(window_s=span_s, ops=ops, latencies_s=latencies,
               setup_s=setup_s, peak_bytes=peak, data_bytes=dep.data_bytes,
               chips=cell.chips, spans=spans.records, compiles=in_window,
-              trace=summary, peaks=peaks, needed_bytes=needed)
+              trace=summary, peaks=peaks,
+              needed_bytes=lambda: dep.needed_bytes(windows))
 
     # the check: the program's state is read, then freed, before the
     # reference runs
-    final_served = np.asarray(loop.table[rows_to_check])
-    ledger, error = loop.ledger, loop.error
-    del loop, svc, dep.table, dep.values
-    ref = reference.Reference(cfg, dep.words, dep.col)
+    checked = dep.read_back()
+    error = loop.error
+    del loop, svc
 
     def judge(stand_in: bool):
-        checks = reference.compare(ledger, ref, rows_to_check, final_served,
-                                   control=stand_in)
+        checks = dep.judge(stand_in)
         checks["failed"] = {"value": failed, "limit": 0}
         return all(v["value"] <= v["limit"] for v in checks.values()), checks
     correct, checks = judge(control)
@@ -582,8 +450,7 @@ def _run(cell, seed, seconds, trace, t_start, out_dir, control, devices,
         line["breakdown"] = {"device_ops": summary["device_ops"],
                              "idle_gaps": summary["idle_gaps"]}
     line["facts"] = {"windows": len(windows), "drain_s": t_drained - end,
-                     "reads_checked": len(ledger.reads),
-                     "rows_checked": int(rows_to_check.size),
+                     **checked,
                      "compiles_in_window": in_window,
                      "error": error}
     if control:     # what the program served, judged the same way
@@ -594,8 +461,8 @@ def _run(cell, seed, seconds, trace, t_start, out_dir, control, devices,
 
 
 def _xplane(trace_dir: Path) -> Path:
-    found = sorted(Path(trace_dir).rglob("*.xplane.pb"),
-                   key=lambda p: p.stat().st_mtime)
+    found = sorted((p.stat().st_mtime, p)
+                   for p in Path(trace_dir).rglob("*.xplane.pb"))
     if not found:
         raise RuntimeError(f"the profiler wrote no trace under {trace_dir}")
-    return found[-1]
+    return found[-1][1]

@@ -13,15 +13,13 @@ sys.path.insert(0, str(BENCH))
 
 PEAKS = {"hbm_bytes_per_s": 819e9}
 
-TINY_TABLE = {"rows": 2048, "row_words": 16, "dtype": "float32",
-              "value_range": 1024, "check_rows": 512,
-              "service": {"tile_size": 256, "auto_flush": 4,
-                          "max_batch": 32}}
-TINY_HIST = {"rows": 1024, "row_words": 0, "dtype": "float32",
-             "value_range": 1024, "check_rows": 1024,
-             "total_keys": 16384, "max_key": 1024,
-             "service": {"tile_size": 256, "auto_flush": 4,
-                         "max_batch": 32}}
+TINY_SERVICE = {"tile_size": 256, "auto_flush": 4, "max_batch": 32}
+TINY_TABLE = {"kind": "keyed", "rows": 2048, "row_words": 16,
+              "dtype": "float32", "value_range": 1024, "check_rows": 512,
+              "service": TINY_SERVICE}
+TINY_HIST = {"kind": "keyed", "rows": 1024, "row_words": 0,
+             "dtype": "float32", "value_range": 1024, "check_rows": 1024,
+             "total_keys": 16384, "max_key": 1024, "service": TINY_SERVICE}
 TINY_A = {"loop": "closed", "clients": 4, "request_ops": 256,
           "read_fraction": 0.5, "key_distribution": "scrambled_zipfian",
           "zipf_constant": 0.99, "keys": "host", "pool_per_client": 4,
@@ -43,10 +41,12 @@ def _metric(name, **kw):
 
 def make_root(tmp: Path) -> Path:
     """A checkout root holding three tiny cells and the real metric
-    readers."""
+    readers and deployment kinds."""
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir()
     shutil.copytree(BENCH / "metrics", tmp / "bench" / "metrics")
+    shutil.copytree(BENCH / "kinds", tmp / "bench" / "kinds",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     for name, body in (("tiny_table", TINY_TABLE), ("tiny_hist", TINY_HIST)):
         (tmp / "bench" / "configs" / f"{name}.json").write_text(
             json.dumps(body))

@@ -1,11 +1,13 @@
-"""The harness finds a configuration, a traffic mix and a metric by name,
-from files of their own, with no edit to any file already there."""
+"""The harness finds a configuration, a traffic mix, a metric and a
+deployment kind by name, from files of their own, with no edit to any file
+already there."""
 import json
 
 import pytest
-from conftest import TINY_A, TINY_TABLE, run_tiny
+from conftest import TINY_A, TINY_SERVICE, TINY_TABLE, run_tiny
 
 import harness
+from repro.core import bulk_ops
 
 
 def test_new_config_mix_and_metric_are_picked_up(tiny_root):
@@ -35,6 +37,177 @@ def test_new_config_mix_and_metric_are_picked_up(tiny_root):
     assert line["correct"]
     assert line["metrics"]["requests_seen"]["value"] == line["attempted"]
     assert all(p.read_bytes() == b for p, b in before.items())
+
+
+# A kind that no cell of the benchmark has: each client follows a chain of
+# keys through a fixed successor table, the next request's keys being the
+# gather of the last one's, made on the device, and lowers a 1-D table to
+# the minimum of its values at each key it visits.
+TOY_KIND = '''
+import dataclasses
+
+import numpy as np
+
+
+def check(cfg, mix):
+    if cfg["rows"] & (cfg["rows"] - 1):
+        raise ValueError("rows must be a power of two")
+
+
+def first_keys(rows, lanes, clients, seed, xp):
+    lane = xp.arange(clients * lanes, dtype=xp.uint32)
+    k = lane * xp.uint32(2654435761) + xp.uint32(seed % 2**32)
+    return (k % xp.uint32(rows)).astype(xp.int32).reshape(clients, lanes)
+
+
+def successor(keys, rows):              # a permutation: 5 is odd
+    return (keys * 5 + 1) % rows
+
+
+def lowered_to(keys, c, xp):
+    return ((keys * 37 + c * 11) % 1009).astype(xp.int32)
+
+
+def initial(rows, xp):
+    return (1009 + xp.arange(rows, dtype=xp.int32) % 7).astype(xp.int32)
+
+
+@dataclasses.dataclass
+class Step:
+    client: int
+    keys: object
+    parts: tuple = ("follow", "lower")
+
+
+def build(cfg, mix, seed, chips, devices):
+    import jax
+    import jax.numpy as jnp
+    rows, lanes = cfg["rows"], mix["lanes"]
+
+    def make():
+        return (initial(rows, jnp),
+                successor(jnp.arange(rows, dtype=jnp.int32), rows),
+                first_keys(rows, lanes, mix["clients"], seed, jnp))
+    table, nxt, keys = jax.jit(make)()
+    return Toy(cfg, mix, seed, table, nxt, list(keys))
+
+
+class Toy:
+    mesh = None
+
+    def __init__(self, cfg, mix, seed, table, nxt, keys):
+        self.cfg, self.mix, self.seed = cfg, mix, seed
+        self.table, self.nxt, self.next_keys = table, nxt, keys
+        self.data_bytes = table.nbytes + nxt.nbytes
+        self.sent = [0] * mix["clients"]
+
+    def request(self, c):
+        self.sent[c] += 1
+        return Step(c, self.next_keys[c])
+
+    def submit(self, step, part, client):
+        import jax.numpy as jnp
+        if part == "follow":
+            return client.submit_gather(self.nxt, step.keys)
+        values = lowered_to(step.keys, step.client, jnp)
+        return client.submit_rmw(self.table, step.keys, values, op="MIN")
+
+    def closed(self, window):
+        for step, part, out in window:
+            if part == "lower" and out is not None:
+                self.table = out
+        return sum(int(step.keys.shape[0]) for step, _, _ in window)
+
+    def retired(self, step, outputs):
+        if outputs is not None:
+            self.next_keys[step.client] = outputs[0]
+
+    def drained(self):
+        pass
+
+    def ops(self, step):
+        return 2 * int(step.keys.shape[0])
+
+    def needed_bytes(self, windows):
+        return 12 * sum(windows)        # index, row and value per lane
+
+    def read_back(self):
+        self.final = np.asarray(self.table)
+        self.table = self.nxt = self.next_keys = None
+        return {"rows_checked": int(self.final.size)}
+
+    def judge(self, control):
+        rows, lanes = self.cfg["rows"], self.mix["lanes"]
+        want = initial(rows, np)
+        keys = first_keys(rows, lanes, self.mix["clients"], self.seed, np)
+        for c, n in enumerate(self.sent):
+            k = keys[c]
+            for _ in range(n):
+                np.minimum.at(want, k, lowered_to(k, c, np))
+                k = successor(k, rows)
+        got = want.astype(np.int8) if control else self.final
+        gap = int(np.max(np.abs(got.astype(np.int64) - want)))
+        return {"state_gap": {"value": gap, "limit": 0}}
+'''
+
+
+def _add_toy(root):
+    bench = root / "bench"
+    (bench / "kinds" / "toy.py").write_text(TOY_KIND)
+    (bench / "configs" / "toy_chain.json").write_text(json.dumps(
+        {"kind": "toy", "rows": 1024, "service": TINY_SERVICE}))
+    (bench / "traffic" / "toy_walk.json").write_text(json.dumps(
+        {"loop": "closed", "clients": 4, "lanes": 64,
+         "warm_requests_per_client": 2}))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "toy_chain",
+                            "file": "bench/configs/toy_chain.json"})
+    spec["workloads"].append({"name": "toy", "config": "toy_chain",
+                              "traffic": "toy_walk", "chips": 1})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+
+def test_new_kind_is_new_files_only(tiny_root):
+    bench = tiny_root / "bench"
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    _add_toy(tiny_root)
+    line = run_tiny(tiny_root, "toy", seed=2**40 + 5)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["checks"]["state_gap"]["value"] == 0
+    assert line["attempted"] > 0
+    assert line["metrics"]["ops_per_s"]["value"] > 0
+    assert all(p.read_bytes() == b for p, b in before.items())
+    # the control, the state held in int8, is judged in its place
+    line = run_tiny(tiny_root, "toy", seed=9, control=True)
+    assert line["correct"] is False and line["program"]["correct"] is True
+
+
+def test_new_kind_with_half_its_lanes_dropped_is_not_correct(tiny_root,
+                                                             monkeypatch):
+    _add_toy(tiny_root)
+    orig = bulk_ops.bulk_rmw
+
+    def half(table, idx, values, **kw):
+        n = idx.shape[0] // 2
+        cond = kw.pop("cond", None)
+        return orig(table, idx[:n], values[:n],
+                    cond=None if cond is None else cond[:n], **kw)
+    monkeypatch.setattr(bulk_ops, "bulk_rmw", half)
+    line = run_tiny(tiny_root, "toy", seed=21)
+    assert line["correct"] is False and line["failed"] == 0
+
+
+@pytest.mark.parametrize("kind", [None, "sorted_set"],
+                         ids=["missing", "unknown"])
+def test_a_configuration_must_name_a_known_kind(tiny_root, kind):
+    path = tiny_root / "bench" / "configs" / "tiny_table.json"
+    cfg = {k: v for k, v in json.loads(path.read_text()).items()
+           if k != "kind"}
+    if kind:
+        cfg["kind"] = kind
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(ValueError, match=r"known kinds: \['keyed'\]"):
+        harness.find_cell("a", tiny_root)
 
 
 BAD_SIZES = {
