@@ -455,9 +455,6 @@ class _Analyzer:
             lo = self.tile(ins.ts1)
             hi = self.tile(ins.ts2)
             has_cond = self._cond(ins.tc)
-            cap = self.reg(ins.rs1)
-            cap_hi = (self.ts if not cap.is_point or cap.lo < 0
-                      else min(int(cap.lo), self.ts))
             conditional = has_cond or lo.conditional or hi.conditional
             # outer counters are lane numbers; unfilled slots stay 0
             self.define(ins.td1, TileState(
@@ -469,8 +466,9 @@ class _Analyzer:
             self.define(ins.td2, TileState(
                 inner, _join_prov(lo.prov, hi.prov), "i32", conditional),
                 implicit=False)
+            # the whole fused stream's count, uncapped by the tile
             self.define("_rng_total",
-                        TileState(Interval(0, max(cap_hi, 0)), "affine",
+                        TileState(Interval(0, 2 ** 31 - 1), "affine",
                                   "i32", conditional), implicit=True)
             self.define(ins.td1 + "__mask",
                         TileState(Interval(0, 1), "affine", "i32",

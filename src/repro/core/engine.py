@@ -298,17 +298,17 @@ class Engine:
             cap = self._reg(regs, ins.rs1)
             cap = self.tile_size if (isinstance(cap, int) and cap < 0) \
                 else int(cap)
+            start = self._reg(regs, ins.rs2)
             outer, inner, total = range_fuser.fuse_ranges(
-                spd[ins.ts1], spd[ins.ts2], capacity=cap,
+                spd[ins.ts1], spd[ins.ts2], capacity=cap, start=start,
                 cond=self._cond(spd, ins.tc))
             spd[ins.td1] = outer
             spd[ins.td2] = inner
             spd["_rng_total"] = total
-            # validity mask of the fused stream (the hardware's finish bits):
-            # downstream stores/RMWs must be guarded by it.
-            spd[ins.td1 + "__mask"] = (
-                jnp.arange(outer.shape[0], dtype=jnp.int32) < total
-            ).astype(jnp.int32)
+            # validity mask of this tile of the fused stream (the hardware's
+            # finish bits): downstream stores/RMWs must be guarded by it.
+            spd[ins.td1 + "__mask"] = range_fuser.fused_valid_mask(
+                total, cap, start).astype(jnp.int32)
         else:
             raise TypeError(f"unknown instruction {ins!r}")
 

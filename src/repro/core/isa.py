@@ -266,6 +266,11 @@ class RNG(Instr):
 
     Writes outer iteration numbers to td1 and inner induction values to td2,
     compacted; rs1 holds the output-capacity register (defaults to tile).
+    rs2 holds the start operand: the first fused position of this tile, so
+    a stream longer than the capacity is issued as successive tiles (start
+    0, capacity, 2*capacity, ...) without a recompile when it is a named
+    register. ``_rng_total`` receives the whole stream's uncapped count
+    and ``td1 + "__mask"`` this tile's valid lanes (start + lane < total).
     """
     td1: str
     td2: str
@@ -273,6 +278,7 @@ class RNG(Instr):
     ts2: str
     rs1: Reg = -1
     tc: Optional[str] = None
+    rs2: Reg = 0
 
     def defs(self):
         return (self.td1, self.td2)

@@ -43,7 +43,8 @@ import os
 import weakref
 from collections import OrderedDict
 from functools import partial
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +75,19 @@ class Ticket:
     """Handle returned by submit; redeem via ``poll``/``result``."""
     tid: int
     tenant: str
+
+
+class ProgramResult(NamedTuple):
+    """A program launch's result: ``(env, spd)``, the regions after the
+    program and its scratchpad tiles. Like a JAX array it says whether the
+    device has finished (``is_ready``), so a caller can poll a retired
+    launch without blocking."""
+    env: dict
+    spd: dict
+
+    def is_ready(self) -> bool:
+        return all(x.is_ready() for x in jax.tree_util.tree_leaves(self)
+                   if hasattr(x, "is_ready"))
 
 
 @dataclasses.dataclass
@@ -808,9 +822,11 @@ class Scheduler:
         if node.backend != "vmap":
             if len(members) == 1:
                 self.stats["singleton_groups"] += 1
-            for sub in members:
-                exe = self.engine.executable(sub.program)
-                self._results[sub.ticket.tid] = exe(sub.env, sub.regs, {})
+            with TraceAnnotation("dx.program.run", bytes=0):
+                for sub in members:
+                    exe = self.engine.executable(sub.program)
+                    self._results[sub.ticket.tid] = ProgramResult(
+                        *exe(sub.env, sub.regs, {}))
             ctx.group_reports.append(GroupReport(
                 len(members), prog.name, vmapped=False, fell_back=False,
                 _coalescing_thunk=thunk))
@@ -819,10 +835,12 @@ class Scheduler:
         exe = self.engine.executable(prog, batch=len(members),
                                      shared=node.shared)
         try:
-            outs = exe.run_batch([s.env for s in members],
-                                 [s.regs for s in members])
+            with TraceAnnotation("dx.program.run",
+                                 bytes=_stacked_bytes(members, node.shared)):
+                outs = exe.run_batch([s.env for s in members],
+                                     [s.regs for s in members])
             for sub, out in zip(members, outs):
-                self._results[sub.ticket.tid] = out
+                self._results[sub.ticket.tid] = ProgramResult(*out)
             self.stats["vmap_groups"] += 1
             ctx.group_reports.append(GroupReport(
                 len(members), prog.name, vmapped=True, fell_back=False,
@@ -831,9 +849,11 @@ class Scheduler:
             # vmap could not trace this program shape: run each member
             # through the (still cached) single-program executable.
             self.stats["vmap_fallbacks"] += 1
-            for sub in members:
-                exe1 = self.engine.executable(sub.program)
-                self._results[sub.ticket.tid] = exe1(sub.env, sub.regs, {})
+            with TraceAnnotation("dx.program.run", bytes=0):
+                for sub in members:
+                    exe1 = self.engine.executable(sub.program)
+                    self._results[sub.ticket.tid] = ProgramResult(
+                        *exe1(sub.env, sub.regs, {}))
             ctx.group_reports.append(GroupReport(
                 len(members), prog.name, vmapped=False, fell_back=True,
                 _coalescing_thunk=thunk))
@@ -892,6 +912,22 @@ class Scheduler:
         ctx.rmw_members.setdefault(node.table_id, []).extend(node.members)
 
 
+def _stacked_bytes(members: Sequence, shared) -> int:
+    """Bytes a vmapped group copies through its lane axis: every region
+    not shared is stacked in, and each one the program writes is taken
+    back out per lane."""
+    prog = members[0].program
+    written = {ins.base for ins in prog.instrs
+               if isinstance(ins, (isa.IST, isa.IRMW, isa.SST))}
+    total = 0
+    for sub in members:
+        for k, v in sub.env.items():
+            if k not in shared:
+                nbytes = int(np.size(v)) * np.dtype(v.dtype).itemsize
+                total += nbytes * (2 if k in written else 1)
+    return total
+
+
 def _bucket_pow2(n: int) -> int:
     """Smallest power of two >= n, floored at 16.
 
@@ -945,9 +981,11 @@ def _static_index_streams(sub: plan_nodes.ProgramNode) \
     """Best-effort static evaluation of each ILD's index stream.
 
     Walks the program propagating tiles computable from python-int regs and
-    env contents (SLD with int start/stride, ILD through a known tile, ALUS
-    with int operands). Unresolvable tiles (RNG outputs, traced regs,
-    condition-masked chains) simply drop out — this feeds *reporting* only.
+    host-resident env contents (SLD with int start/stride, ILD through a
+    known tile, ALUS with int operands). Unresolvable tiles (RNG outputs,
+    traced regs, condition-masked chains, regions already on the device —
+    reading one back would sync the window for a report) simply drop out
+    — this feeds *reporting* only.
     """
     known: Dict[str, np.ndarray] = {}
     streams: Dict[str, list] = {}
@@ -959,12 +997,16 @@ def _static_index_streams(sub: plan_nodes.ProgramNode) \
             return v if isinstance(v, (int, float, np.integer)) else None
         return r
 
+    def _host(region):
+        base = sub.env.get(region)
+        return base if isinstance(base, np.ndarray) else None
+
     for ins in sub.program.instrs:
         if isinstance(ins, isa.SLD) and ins.tc is None:
             start, stride = _reg(ins.rs1), _reg(ins.rs3)
-            if start is None or stride is None or ins.base not in sub.env:
+            base = _host(ins.base)
+            if start is None or stride is None or base is None:
                 continue
-            base = np.asarray(sub.env[ins.base])
             addr = int(start) + np.arange(ts, dtype=np.int64) * int(stride)
             known[ins.td] = base[np.clip(addr, 0, base.shape[0] - 1)]
         elif isinstance(ins, isa.ILD):
@@ -977,8 +1019,8 @@ def _static_index_streams(sub: plan_nodes.ProgramNode) \
                 count = min(ts, int(n))
             streams.setdefault(ins.base, []).append(
                 idx[:count].astype(np.int64))
-            base = np.asarray(sub.env[ins.base])
-            if base.ndim == 1:
+            base = _host(ins.base)
+            if base is not None and base.ndim == 1:
                 # propagate ignoring the condition mask: lanes past the trip
                 # count are cut by [:count] above; this feeds reporting only.
                 known[ins.td] = base[

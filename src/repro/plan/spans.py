@@ -17,6 +17,8 @@ covers):
     dx.flush.emit           plan_emit.execute
       dx.prefetch.<kind>    one per route-stage prefetch
       dx.emit.<kind>.<be>   one per root node
+        dx.program.run      a program group's executable call; ``bytes=``
+                            of the regions stacked through its vmap lanes
     dx.flush.report         the FlushReport and the plan's strip
   dx.sync.<site>            a device->host read (``to_host``), ``bytes=``
   dx.h2d.<site>             a host->device upload (``to_device``, or a

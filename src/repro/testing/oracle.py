@@ -217,6 +217,7 @@ class OracleEngine:
             lo = spd[ins.ts1].astype(np.int32)
             hi = spd[ins.ts2].astype(np.int32)
             cond = self._cond(spd, ins.tc)
+            start = int(self._reg(regs, ins.rs2))
             outer = np.zeros(cap, np.int32)
             inner = np.zeros(cap, np.int32)
             p = 0
@@ -224,18 +225,15 @@ class OracleEngine:
                      else range(lo.shape[0]))
             for i in lanes:                 # the naive nested loop itself
                 for j in range(int(lo[i]), int(hi[i])):
-                    if p >= cap:            # capacity truncation (engine
-                        break               # clamps `total` identically)
-                    outer[p] = i
-                    inner[p] = j
+                    if start <= p < start + cap:    # this tile's lanes
+                        outer[p - start] = i
+                        inner[p - start] = j
                     p += 1
-                if p >= cap:
-                    break
             spd[ins.td1] = outer
             spd[ins.td2] = inner
-            spd["_rng_total"] = np.int32(p)
+            spd["_rng_total"] = np.int32(p)     # the whole stream
             spd[ins.td1 + "__mask"] = (
-                np.arange(cap, dtype=np.int32) < p).astype(np.int32)
+                np.arange(cap, dtype=np.int32) < p - start).astype(np.int32)
         else:
             raise TypeError(f"unknown instruction {ins!r}")
 

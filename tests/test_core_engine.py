@@ -9,6 +9,7 @@ from repro.core import (Access, BinOp, Compare, Engine, LegalityError, Load,
                         Pattern, RangeLoop, Var, bulk_gather, bulk_rmw,
                         bulk_scatter, compile_pattern, fuse_ranges, isa,
                         run_tiled, structural_signature)
+from repro.core.range_fuser import fused_valid_mask
 
 
 @pytest.fixture(scope="module")
@@ -96,10 +97,13 @@ class TestRangeFuser:
         assert int(total) == 3
 
     def test_capacity_clamp(self):
+        # the tile holds 16 lanes; the total is the whole stream's, so the
+        # 384 lanes past the capacity are left for later tiles, not lost
         lo = jnp.zeros((4,), jnp.int32)
         hi = jnp.full((4,), 100, jnp.int32)
         _, _, total = fuse_ranges(lo, hi, capacity=16)
-        assert int(total) == 16
+        assert int(total) == 400
+        assert int(fused_valid_mask(total, 16).sum()) == 16
 
 
 # ---------------------------------------------------------------------------

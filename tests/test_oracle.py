@@ -75,7 +75,7 @@ class TestOracleInstructions:
         spd0 = {"lo": np.zeros(8, np.int32),
                 "hi": np.full(8, 3, np.int32)}
         _, spd = OracleEngine(8).run(p, {}, spd=spd0)
-        assert int(spd["_rng_total"]) == 4
+        assert int(spd["_rng_total"]) == 24     # the whole stream
         np.testing.assert_array_equal(spd["o"], [0, 0, 0, 1])
         np.testing.assert_array_equal(spd["j"], [0, 1, 2, 0])
         np.testing.assert_array_equal(spd["o__mask"], [1, 1, 1, 1])
