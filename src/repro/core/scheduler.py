@@ -822,11 +822,7 @@ class Scheduler:
         if node.backend != "vmap":
             if len(members) == 1:
                 self.stats["singleton_groups"] += 1
-            with TraceAnnotation("dx.program.run", bytes=0):
-                for sub in members:
-                    exe = self.engine.executable(sub.program)
-                    self._results[sub.ticket.tid] = ProgramResult(
-                        *exe(sub.env, sub.regs, {}))
+            self._run_each(members)
             ctx.group_reports.append(GroupReport(
                 len(members), prog.name, vmapped=False, fell_back=False,
                 _coalescing_thunk=thunk))
@@ -849,14 +845,18 @@ class Scheduler:
             # vmap could not trace this program shape: run each member
             # through the (still cached) single-program executable.
             self.stats["vmap_fallbacks"] += 1
-            with TraceAnnotation("dx.program.run", bytes=0):
-                for sub in members:
-                    exe1 = self.engine.executable(sub.program)
-                    self._results[sub.ticket.tid] = ProgramResult(
-                        *exe1(sub.env, sub.regs, {}))
+            self._run_each(members)
             ctx.group_reports.append(GroupReport(
                 len(members), prog.name, vmapped=False, fell_back=True,
                 _coalescing_thunk=thunk))
+
+    def _run_each(self, members) -> None:
+        """Each member through its (cached) single-program executable."""
+        with TraceAnnotation("dx.program.run", bytes=0):
+            for sub in members:
+                exe = self.engine.executable(sub.program)
+                self._results[sub.ticket.tid] = ProgramResult(
+                    *exe(sub.env, sub.regs, {}))
 
     def _execute_gathers(self, node: plan_nodes.FusedGather,
                          ctx: plan_emit.EmitContext) -> None:
