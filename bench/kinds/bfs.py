@@ -16,15 +16,22 @@ search keys (client c takes keys c, c + clients, ...), into a parent
 table of its own: ``(level << scale) | parent`` words, ``MIN``-updated.
 
 The check reads every client's parent table back and compares it whole,
-vertex by vertex, with a plain NumPy replay of the same requests on the
-CSR the build made: the reference finds each level's frontier from its
-own table and applies each recorded request's lanes (its level, frontier
-tile and start, in CSR order) with ``np.minimum.at``. It imports nothing
-of the program. The control applies each lane as an overwrite in CSR
-order instead (the last writer of a level wins): a valid BFS tree whose
-parents are not the smallest.
+vertex by vertex, with a plain NumPy replay on the CSR the build made.
+The replay runs the client's searches from its own roots and its own
+frontiers, relaxing every lane of each completed level with
+``np.minimum.at``, and of the level still in flight the lanes of as many
+requests as the client completed there, each request's extent found
+from its own frontier (tiles of ``chunk_lanes`` vertices, at most
+``chunk_lanes`` entries a request). Only the count of completed requests
+comes from the run, so lanes a program drops or a request it skips show
+up as a gap. It imports nothing of the program. The control applies
+each lane as an overwrite in CSR order instead (the last writer of a
+level wins): a valid BFS tree whose parents are not the smallest.
 """
 from __future__ import annotations
+
+import math
+import time
 
 import numpy as np
 
@@ -33,6 +40,7 @@ import data
 GOLDEN = 0x9E3779B1
 CANDIDATES = 4096            # drawn to find the search keys
 REPLAY_LANES = 1 << 24       # lanes the reference expands at once
+BUILD_BLOCK = 1 << 24        # edges the build makes at once, at most
 
 
 def check(cfg: dict, mix: dict) -> None:
@@ -51,8 +59,6 @@ def check(cfg: dict, mix: dict) -> None:
     if mix["chunk_lanes"] != cfg["service"]["tile_size"]:
         raise ValueError(f"chunk_lanes {mix['chunk_lanes']} is not the "
                          f"service's tile of {cfg['service']['tile_size']}")
-    if cfg["undirected_edges"] % cfg["build_block_edges"]:
-        raise ValueError("the build's blocks do not divide the edges")
     if mix["clients"] > cfg["search_keys"]:
         raise ValueError(f"{mix['clients']} clients for "
                          f"{cfg['search_keys']} search keys")
@@ -69,25 +75,28 @@ def _threshold(p: float) -> int:
     return min(int(round(p * 2 ** 32)), 2 ** 32 - 1)
 
 
-def _words(seed: int, count: int) -> list:
-    return [int(w) for w in np.random.SeedSequence(
-        [int(seed), 17]).generate_state(count, np.uint32)]
+def _words(seed: int, count: int) -> np.ndarray:
+    return np.random.SeedSequence([int(seed), 17]).generate_state(
+        count, np.uint32)
 
 
-def _permute(x, keys: list, scale: int, xp):
+def _permute(x, keys, scale: int):
     """A seeded bijection of [0, 2**scale): odd multiplies, adds and
-    xor-shifts, each invertible modulo 2**scale."""
-    u = xp.uint32
+    xor-shifts, each invertible modulo 2**scale (``keys``: uint32 pairs)."""
+    import jax.numpy as jnp
+    u = jnp.uint32
     mask = u((1 << scale) - 1)
-    for k in range(0, len(keys), 2):
-        x = (x * u(keys[k] | 1) + u(keys[k + 1])) & mask
+    for k in range(keys.shape[0]):
+        x = (x * (keys[k, 0] | u(1)) + keys[k, 1]) & mask
         x = x ^ (x >> u(scale // 2 + 1))
     return x
 
 
-def kronecker_block(first, count: int, cfg: dict, words: list):
+def kronecker_block(first, count: int, cfg: dict, words):
     """Endpoints (row, col) of edges ``first`` .. ``first + count - 1``,
-    vertex labels permuted (int32, on the device)."""
+    vertex labels permuted (int32, on the device). ``words``: the seed's
+    ``2 * scale + 6`` uint32 words, traced, so that one compile serves
+    every seed."""
     import jax
     import jax.numpy as jnp
     u = jnp.uint32
@@ -96,7 +105,7 @@ def kronecker_block(first, count: int, cfg: dict, words: list):
     t_row = u(_threshold(ab))
     t_col_hi = u(_threshold(ini["C"] / (1 - ab)))     # row bit 1
     t_col_lo = u(_threshold(ini["A"] / ab))           # row bit 0
-    keys = jnp.asarray(words[:2 * scale], u).reshape(scale, 2)
+    keys = words[:2 * scale].reshape(scale, 2)
     e = (jnp.asarray(first).astype(u) + jnp.arange(count, dtype=u)) * \
         u(GOLDEN)
 
@@ -109,40 +118,39 @@ def kronecker_block(first, count: int, cfg: dict, words: list):
                 col | (jj.astype(u) << bit.astype(u)))
     row, col = jax.lax.fori_loop(0, scale, level, (jnp.zeros(count, u),
                                                    jnp.zeros(count, u)))
-    perm = words[2 * scale:]
-    return (_permute(row, perm, scale, jnp).astype(jnp.int32),
-            _permute(col, perm, scale, jnp).astype(jnp.int32))
+    perm = words[2 * scale:].reshape(-1, 2)
+    return (_permute(row, perm, scale).astype(jnp.int32),
+            _permute(col, perm, scale).astype(jnp.int32))
 
 
 def build_csr(cfg: dict, seed: int, device):
     """(indptr, adj) on ``device``: a degree pass, then a fill pass, over
-    blocks of ``build_block_edges`` edges made anew in each."""
+    blocks of at most ``BUILD_BLOCK`` edges made anew in each. The seed
+    enters as an argument, so the two passes compile once for every
+    seed."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     n, m = cfg["vertices"], cfg["undirected_edges"]
-    block = cfg["build_block_edges"]
+    block = math.gcd(m, BUILD_BLOCK)      # divides the edges
     blocks = m // block
-    words = _words(seed, 2 * cfg["scale"] + 6)
     one = SingleDeviceSharding(device)
+    words = jax.device_put(_words(seed, 2 * cfg["scale"] + 6), one)
 
-    def edges(b):
-        return kronecker_block(b * block, block, cfg, words)
-
-    def degrees():
+    def degrees(words):
         def body(b, deg):
-            row, col = edges(b)
+            row, col = kronecker_block(b * block, block, cfg, words)
             return deg.at[row].add(1).at[col].add(1)
         deg = jax.lax.fori_loop(0, blocks, body, jnp.zeros(n, jnp.int32))
         return jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(deg)])
 
-    def fill(indptr):
+    def fill(words, indptr):
         lane = jnp.arange(2 * block, dtype=jnp.int32)
 
         def body(b, carry):
             adj, cursor = carry
-            row, col = edges(b)
+            row, col = kronecker_block(b * block, block, cfg, words)
             src, dst = jax.lax.sort((jnp.concatenate([row, col]),
                                      jnp.concatenate([col, row])),
                                     num_keys=1, is_stable=True)
@@ -154,8 +162,8 @@ def build_csr(cfg: dict, seed: int, device):
             0, blocks, body, (jnp.zeros(2 * m, jnp.int32), indptr[:-1]))
         return adj
 
-    indptr = jax.jit(degrees, out_shardings=one)()
-    adj = jax.jit(fill, out_shardings=one)(indptr)
+    indptr = jax.jit(degrees, out_shardings=one)(words)
+    adj = jax.jit(fill, out_shardings=one)(words, indptr)
     return indptr, adj
 
 
@@ -165,14 +173,14 @@ def search_keys(indptr, count: int, seed: int) -> list:
     import jax
     import jax.numpy as jnp
     n = indptr.shape[0] - 1
-    w = _words(int(seed) + 1, 1)[0]
 
-    def draw():
+    def draw(indptr, w):
         lane = jnp.arange(CANDIDATES, dtype=jnp.uint32)
-        v = (data.mix32(lane * jnp.uint32(GOLDEN) ^ jnp.uint32(w), jnp)
+        v = (data.mix32(lane * jnp.uint32(GOLDEN) ^ w, jnp)
              % jnp.uint32(n)).astype(jnp.int32)
         return v, indptr[v + 1] - indptr[v]
-    v, deg = (np.asarray(a) for a in jax.jit(draw)())
+    v, deg = (np.asarray(a) for a in jax.jit(draw)(
+        indptr, _words(int(seed) + 1, 1)[0]))
     keys = []
     for vertex, d in zip(v.tolist(), deg.tolist()):
         if d > 0 and vertex not in keys:
@@ -191,9 +199,13 @@ UNREACHED = np.int32(2 ** 31 - 1)
 
 
 class Replay:
-    """One client's search replayed on the host CSR: ``search(root,
-    tiles)`` applies its requests, in order, each given as (level, first,
-    start) and relaxing at most ``capacity`` lanes."""
+    """One client's searches replayed on the host CSR, from its own roots
+    and its own frontiers: ``run(roots, requests)`` does what that many
+    requests of the client do, in the order they are made (a level's
+    frontier queue in tiles of ``capacity`` vertices; each tile's entries,
+    in CSR order, in requests of at most ``capacity`` lanes, at least one
+    a tile), and returns the table of the search it ends in. Only the
+    count of requests comes from the run."""
 
     def __init__(self, indptr: np.ndarray, adj: np.ndarray, scale: int,
                  capacity: int, overwrite: bool):
@@ -201,46 +213,63 @@ class Replay:
         self.n = indptr.shape[0] - 1
         self.shift, self.capacity = scale, capacity
         self.overwrite = overwrite
-        self.levels = {}     # level -> (frontier ascending, its offsets)
+        self.levels = {}     # (search, level) -> (frontier, its offsets)
 
-    def search(self, root: int, tiles: list) -> np.ndarray:
+    def run(self, roots: list, requests: int) -> np.ndarray:
+        search = 0
+        while True:
+            table, left = self.search(search, roots[search % len(roots)],
+                                      requests)
+            if left is None:
+                return table
+            search, requests = search + 1, left
+
+    def search(self, search: int, root: int, budget: int):
+        """(table, requests left over once the search ended), or (table,
+        None) where the budget ran out inside it."""
         table = np.full(self.n, UNREACHED, np.int32)
         table[root] = root
-        for level in sorted({t[0] for t in tiles}):
+        level = 0
+        while True:
             front = np.flatnonzero((table >> self.shift) == level)
+            if not front.size:
+                return table, budget
+            if level + 1 > (int(UNREACHED) >> self.shift) - 1:
+                raise RuntimeError(f"BFS level {level + 1} does not fit a "
+                                   f"parent word of {self.shift} vertex bits")
             deg = self.indptr[front + 1] - self.indptr[front]
             cum = np.concatenate([[0], np.cumsum(deg, dtype=np.int64)])
-            self.levels[level] = front, cum
-            for a, b in self._ranges(cum, [t[1:] for t in tiles
-                                           if t[0] == level]):
-                for lo in range(a, b, REPLAY_LANES):
-                    dst, src = self.lanes(level, lo,
-                                          min(b, lo + REPLAY_LANES))
-                    self._relax(table, dst,
-                                ((level + 1) << self.shift) | src, level)
-        return table
+            self.levels[(search, level)] = front, cum
+            ends = self.request_ends(cum)
+            if budget < ends.size:      # the level still in flight
+                b = int(ends[budget - 1]) if budget else 0
+                self._apply(table, search, level, b)
+                return table, None
+            self._apply(table, search, level, int(cum[-1]))
+            budget -= ends.size
+            level += 1
 
-    def _ranges(self, cum, tiles) -> list:
-        """Each request's positions in the level's CSR-order stream, as
-        [a, b), with touching ranges merged."""
-        out = []
-        last = cum.size - 1
-        for first, start in tiles:
-            first = min(first, last)
-            end = cum[min(first + self.capacity, last)]
-            a = min(cum[first] + start, end)
-            b = min(a + self.capacity, end)
-            if a >= b:
-                continue
-            if out and out[-1][1] == a:
-                out[-1][1] = b
-            else:
-                out.append([a, b])
-        return out
+    def request_ends(self, cum: np.ndarray) -> np.ndarray:
+        """Where each of a level's requests ends in its CSR-order stream."""
+        cap = self.capacity
+        bounds = cum[np.minimum(np.arange(0, cum.size - 1 + cap, cap),
+                                cum.size - 1)]
+        ends = []
+        for a, b in zip(bounds[:-1].tolist(), bounds[1:].tolist()):
+            ends += list(range(a + cap, b, cap)) + [b]
+        return np.asarray(ends, np.int64)
 
-    def lanes(self, level: int, a: int, b: int):
+    def _apply(self, table, search: int, level: int, b: int) -> None:
+        """Relaxes positions [0, b) of the level's stream, in CSR order."""
+        for lo in range(0, b, REPLAY_LANES):
+            dst, src = self.lanes(search, level, lo,
+                                  min(b, lo + REPLAY_LANES))
+            self._relax(table, dst, ((level + 1) << self.shift) | src,
+                        level)
+
+    def lanes(self, search: int, level: int, a: int, b: int):
         """(neighbour, parent) of positions [a, b) of the level's stream."""
-        front, cum = self.levels[level]
+        front, cum = self.levels[(search, level)]
         v0 = int(np.searchsorted(cum, a, side="right")) - 1
         v1 = int(np.searchsorted(cum, b - 1, side="right")) - 1
         vs = front[v0:v1 + 1]
@@ -275,20 +304,30 @@ class Bfs:
         # first: a program without Graph500 BFS fails here, before the
         # graph is made
         from repro.apps.bfs import Search
+        import jax
 
         self.cfg, self.mix = cfg, mix
         self.clients, self.capacity = mix["clients"], mix["chunk_lanes"]
+        # the set-up's stages, timed to their completion on the device
+        t = [time.perf_counter()]
         self.indptr, self.adj = build_csr(cfg, seed, devices[0])
+        jax.block_until_ready(self.adj)
+        t.append(time.perf_counter())
         self.keys = search_keys(self.indptr, cfg["search_keys"], seed)
+        t.append(time.perf_counter())
         self.searches = [
             Search(c, self.keys[c::self.clients], self.indptr, self.adj,
                    capacity=self.capacity)
             for c in range(self.clients)]
+        t.append(time.perf_counter())
+        self.stages_s = dict(zip(("build", "search_keys", "first_queues"),
+                                 np.diff(t).tolist()))
         self.program = self.searches[0].program
         n = cfg["vertices"]
         self.data_bytes = int(self.adj.nbytes + self.indptr.nbytes
                               + self.clients * n * 4)
-        self.applied = [[] for _ in range(self.clients)]
+        # each client's completed requests, as (level, lanes)
+        self.served = [[] for _ in range(self.clients)]
 
     # -- traffic -------------------------------------------------------------
 
@@ -305,7 +344,7 @@ class Bfs:
         self.searches[tile.client].retire(
             tile, None if outputs is None else outputs[0])
         if outputs is not None:
-            self.applied[tile.client].append(tile)
+            self.served[tile.client].append((tile.level, tile.lanes))
 
     def drained(self) -> None:
         pass
@@ -326,12 +365,13 @@ class Bfs:
         return total
 
     def _distinct(self, t) -> int:
-        replay = self.replays.get((t.client, t.search))
-        if replay is None or t.level not in replay.levels or not t.lanes:
+        replay = self.replays.get(t.client)
+        key = (t.search, t.level)
+        if replay is None or key not in replay.levels or not t.lanes:
             return 0
-        front, cum = replay.levels[t.level]
+        front, cum = replay.levels[key]
         a = int(cum[min(t.first, front.size)]) + t.start
-        dst, _ = replay.lanes(t.level, a, a + t.lanes)
+        dst, _ = replay.lanes(t.search, t.level, a, a + t.lanes)
         seen = np.zeros(replay.n, bool)
         seen[dst] = True
         return int(np.count_nonzero(seen))
@@ -342,30 +382,28 @@ class Bfs:
         self.host_indptr = np.asarray(self.indptr)
         self.host_adj = np.asarray(self.adj)
         self.final = [np.asarray(s.table) for s in self.searches]
-        self.current = [(s.search, s.root) for s in self.searches]
         self.searches = self.indptr = self.adj = None
         self.replays = {}
         return {"vertices_checked": int(sum(f.size for f in self.final)),
-                "requests_checked": int(sum(len(a) for a in self.applied)),
-                "search_keys": self.keys[:8]}
+                "requests_checked": sum(len(s) for s in self.served),
+                "search_keys": self.keys[:8],
+                "setup_stages_s": self.stages_s,
+                "requests_level_lanes": self.served}
 
     def judge(self, control: bool) -> dict:
-        """Every search a client ran is replayed (its frontiers give
-        ``needed_bytes``); the current one's table is compared whole."""
+        """Each client's searches are replayed from its roots for as many
+        requests as it completed, and the table it ends in is compared
+        whole with the client's (the replays' frontiers also give
+        ``needed_bytes``)."""
         gap = 0
-        for c, (current, root) in enumerate(self.current):
-            roots = {t.search: t.root for t in self.applied[c]}
-            roots[current] = root
-            for search, r in sorted(roots.items()):
-                replay = Replay(self.host_indptr, self.host_adj,
-                                self.cfg["scale"], self.capacity, control)
-                want = replay.search(r, [(t.level, t.first, t.start)
-                                         for t in self.applied[c]
-                                         if t.search == search])
-                if search == current:
-                    gap += int(np.count_nonzero(want != self.final[c]))
-                if not control:
-                    self.replays[(c, search)] = replay
+        for c, table in enumerate(self.final):
+            replay = Replay(self.host_indptr, self.host_adj,
+                            self.cfg["scale"], self.capacity, control)
+            want = replay.run(self.keys[c::self.clients],
+                              len(self.served[c]))
+            gap += int(np.count_nonzero(want != table))
+            if not control:
+                self.replays[c] = replay
         return {"parent_gap": {"value": gap, "limit": 0}}
 
 

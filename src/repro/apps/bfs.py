@@ -61,10 +61,18 @@ class Graph:
         return self.adj.shape[0]
 
 
-def make_graph(seed: int = 0, *, n: int = 512, avg_deg: int = 4) -> Graph:
+def make_graph(seed: int = 0, *, n: int = 512, avg_deg: int = 4,
+               kronecker: bool = False) -> Graph:
     """Random directed graph in CSR (degree-capped, self-loops allowed —
-    they relax to a no-op)."""
+    they relax to a no-op). ``kronecker=True`` makes Graph500's graph
+    instead: ``n`` a power of two, ``avg_deg`` its edgefactor
+    (``avg_deg * n`` undirected edges, each level drawing one bit of the
+    row and then one of the column conditioned on it, initiator
+    ``KRONECKER_ABC``), vertex labels randomly permuted, every edge stored
+    in both directions (self-loops and duplicates kept)."""
     rng = np.random.default_rng(seed)
+    if kronecker:
+        return _kronecker(rng, n, avg_deg)
     deg = rng.integers(0, 2 * avg_deg + 1, size=n)
     indptr = np.zeros(n + 1, np.int32)
     indptr[1:] = np.cumsum(deg)
@@ -373,16 +381,12 @@ def search_parents(g: Graph, roots, *, capacity: int, clients: int = 4,
             s.retire(tile, svc.wait(ticket))
 
 
-def kronecker_graph(scale: int, edgefactor: int = 16,
-                    seed: int = 0) -> Graph:
-    """Graph500's Kronecker graph in CSR: ``edgefactor << scale`` edges,
-    each level drawing one bit of the row and then one of the column
-    conditioned on it (initiator ``KRONECKER_ABC``), vertex labels
-    randomly permuted, every edge stored in both directions (self-loops
-    and duplicates kept)."""
+def _kronecker(rng, n: int, edgefactor: int) -> Graph:
+    scale = int(n).bit_length() - 1
+    if n != 1 << scale:
+        raise ValueError(f"a Kronecker graph has 2**scale vertices, not {n}")
     a, b, c = KRONECKER_ABC
-    rng = np.random.default_rng(seed)
-    n, m = 1 << scale, edgefactor << scale
+    m = edgefactor * n
     row = np.zeros(m, np.int64)
     col = np.zeros(m, np.int64)
     for bit in range(scale):
@@ -397,14 +401,6 @@ def kronecker_graph(scale: int, edgefactor: int = 16,
     indptr = np.zeros(n + 1, np.int64)
     indptr[1:] = np.cumsum(np.bincount(src, minlength=n))
     return Graph(indptr.astype(np.int32), dst[order].astype(np.int32))
-
-
-def search_keys(g: Graph, count: int, seed: int = 0) -> np.ndarray:
-    """``count`` distinct roots drawn at random among the vertices of
-    degree at least 1."""
-    deg = np.diff(g.indptr)
-    return np.random.default_rng(seed).permutation(
-        np.flatnonzero(deg > 0))[:count].astype(np.int32)
 
 
 def reference_parents(g: Graph, root: int) -> np.ndarray:

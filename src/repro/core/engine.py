@@ -260,14 +260,10 @@ class Engine:
             idx = spd[ins.ts1].astype(jnp.int32)
             if cond is not None:
                 idx = jnp.where(cond, idx, 0)
-            base = env[ins.base]
-            # a 1-D table is read one lane at a time either way, so sorting
-            # and deduplicating its indices first only adds a sort and an
-            # inverse scatter; row tables keep the coalesced fetch
-            rows = self.optimize and base.ndim == 2
             out = bulk_ops.bulk_gather(
-                base, idx, sort=rows, dedup=rows,
-                use_kernel=self.use_kernel and base.ndim == 2)
+                env[ins.base], idx,
+                sort=self.optimize, dedup=self.optimize,
+                use_kernel=self.use_kernel and env[ins.base].ndim == 2)
             if cond is not None:
                 zshape = (-1,) + (1,) * (out.ndim - 1)
                 out = jnp.where(cond.reshape(zshape), out, 0)

@@ -17,7 +17,7 @@ TINY_GRAPH = {"kind": "bfs", "scale": 10, "edgefactor": 16,
               "vertices": 1024, "undirected_edges": 16384,
               "csr_entries": 32768,
               "initiator": {"A": 0.57, "B": 0.19, "C": 0.19, "D": 0.05},
-              "search_keys": 64, "build_block_edges": 4096,
+              "search_keys": 64,
               "service": {"tile_size": 256, "auto_flush": 4,
                           "max_batch": 4}}
 TINY_SEARCHES = {"loop": "closed", "clients": 4, "chunk_lanes": 256,
@@ -48,6 +48,23 @@ def test_bfs_is_correct_and_its_control_is_not(tiny_root):
     assert line["correct"] is False
     assert line["checks"]["parent_gap"]["value"] > 0
     assert line["program"]["correct"] is True
+
+
+def test_a_fuser_that_caps_its_total_is_not_correct(tiny_root,
+                                                     monkeypatch):
+    """A Range Fuser that reports ``min(total, capacity)``: each tile takes
+    one request and its entries past the capacity are never relaxed."""
+    _add_bfs(tiny_root)
+    orig = range_fuser.fuse_ranges
+
+    def capped(lo, hi, *, capacity, start=0, cond=None):
+        outer, inner, total = orig(lo, hi, capacity=capacity, start=start,
+                                   cond=cond)
+        return outer, inner, jax.numpy.minimum(total, capacity)
+    monkeypatch.setattr(range_fuser, "fuse_ranges", capped)
+    line = run_tiny(tiny_root, "bfs", seed=37)
+    assert line["correct"] is False and line["failed"] == 0
+    assert line["checks"]["parent_gap"]["value"] > 0
 
 
 def test_a_fuser_that_ignores_its_start_is_not_correct(tiny_root,
@@ -168,14 +185,36 @@ def test_replay_is_the_plain_reference():
             level[w] = level[frontier[0]] + 1
             want[w] = p
         frontier = sorted(nxt)
+    # each level's requests: tiles of 4 frontier vertices, at most 4
+    # entries a request and at least one request a tile
     tiles = []
-    for lv in range(max(level.values())):
-        count = sum(1 for x in level.values() if x == lv)
-        for first in range(0, count, 4):
-            tiles += [(lv, first, s0) for s0 in range(0, 200, 4)]
-    table = kind.Replay(indptr, adj, 6, 4, False).search(root, tiles)
+    for lv in range(max(level.values()) + 1):
+        front = sorted(v for v, x in level.items() if x == lv)
+        for first in range(0, len(front), 4):
+            total = sum(indptr[v + 1] - indptr[v]
+                        for v in front[first:first + 4])
+            tiles += [(lv, first, s0) for s0 in range(0, max(total, 1), 4)]
+    replay = kind.Replay(indptr, adj, 6, 4, False)
+    table, left = replay.search(0, root, len(tiles))
+    assert left == 0
     reached = table != kind.UNREACHED
     assert set(np.flatnonzero(reached)) == set(level)
     np.testing.assert_array_equal(table[reached] & 63, want[reached])
     np.testing.assert_array_equal(table[reached] >> 6,
                                   [level[v] for v in np.flatnonzero(reached)])
+
+
+def test_the_build_in_blocks_makes_one_graph(monkeypatch):
+    """The CSR does not depend on how many edges the build makes at once."""
+    kind = harness.find_kind(harness.ROOT, {"kind": "bfs"})
+    whole = [np.asarray(a) for a in kind.build_csr(TINY_GRAPH, 41,
+                                                   jax.devices()[0])]
+    monkeypatch.setattr(kind, "BUILD_BLOCK", 1 << 11)
+    blocks = [np.asarray(a) for a in kind.build_csr(TINY_GRAPH, 41,
+                                                    jax.devices()[0])]
+    np.testing.assert_array_equal(whole[0], blocks[0])
+    assert whole[0][-1] == TINY_GRAPH["csr_entries"]
+    # each vertex has the same neighbours (their order follows the blocks)
+    src = np.repeat(np.arange(TINY_GRAPH["vertices"]), np.diff(whole[0]))
+    a, b = (adj[np.lexsort((adj, src))] for _, adj in (whole, blocks))
+    np.testing.assert_array_equal(a, b)

@@ -13,11 +13,22 @@ from repro.core.scheduler import ProgramResult, Scheduler
 from repro.serve import AccessService
 
 
+def _kronecker(scale, edgefactor, seed):
+    return bfs.make_graph(seed, n=1 << scale, avg_deg=edgefactor,
+                          kronecker=True)
+
+
+def _roots(g, count, seed):
+    """``count`` distinct roots among the vertices of degree at least 1."""
+    return np.random.default_rng(seed).permutation(
+        np.flatnonzero(np.diff(g.indptr) > 0))[:count]
+
+
 @pytest.mark.parametrize("scale,capacity,clients", [
     (10, 64, 4), (11, 256, 3), (12, 512, 4)])
 def test_program_mode_matches_the_reference(scale, capacity, clients):
-    g = bfs.kronecker_graph(scale, 16, seed=scale)
-    roots = bfs.search_keys(g, clients + 2, seed=7)
+    g = _kronecker(scale, 16, scale)
+    roots = _roots(g, clients + 2, seed=7)
     svc = AccessService(tile_size=capacity, auto_flush=0, max_batch=clients)
     got = bfs.search_parents(g, roots, capacity=capacity, clients=clients,
                              service=svc)
@@ -33,8 +44,8 @@ def test_program_mode_matches_the_reference(scale, capacity, clients):
 
 
 def test_reference_parents_are_a_bfs_tree():
-    g = bfs.kronecker_graph(10, 16, seed=1)
-    root = int(bfs.search_keys(g, 1, seed=2)[0])
+    g = _kronecker(10, 16, 1)
+    root = int(_roots(g, 1, seed=2)[0])
     table = bfs.reference_parents(g, root)
     shift = bfs.level_shift(g.n)
     dist = bfs.reference(g, root, levels=g.n)
@@ -50,7 +61,7 @@ def test_reference_parents_are_a_bfs_tree():
 
 
 def test_kronecker_graph_shape():
-    g = bfs.kronecker_graph(10, 16, seed=0)
+    g = _kronecker(10, 16, 0)
     assert g.n == 1024 and g.n_edges == 2 * 16 * 1024
     # each edge in both directions: the adjacency is symmetric
     src = np.repeat(np.arange(g.n), np.diff(g.indptr))
@@ -60,7 +71,7 @@ def test_kronecker_graph_shape():
 
 
 def test_a_tile_reports_its_uncapped_total():
-    g = bfs.kronecker_graph(10, 16, seed=4)
+    g = _kronecker(10, 16, 4)
     indptr, adj = jnp.asarray(g.indptr), jnp.asarray(g.adj)
     root = int(np.argmax(np.diff(g.indptr)))      # a hub
     s = bfs.Search(0, [root], indptr, adj, capacity=16)
@@ -94,7 +105,7 @@ def test_levels_past_the_packing_fail():
 
 def _program_window(sched, env_of):
     prog = bfs.frontier_program(16)
-    g = bfs.kronecker_graph(8, 4, seed=3)
+    g = _kronecker(8, 4, 3)
     indptr, adj = env_of(g.indptr), env_of(g.adj)
     tickets = []
     for c in range(2):

@@ -1,8 +1,6 @@
 """Unit tests: ISA semantics, engine execution, Table-1 workload patterns."""
 import dataclasses
-import re
 
-import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -301,21 +299,3 @@ class TestCompileCache:
         prog, _ = self._prog()
         renamed = dataclasses.replace(prog, name="renamed")
         assert eng.executable(prog) is eng.executable(renamed)
-
-
-@pytest.mark.parametrize("shape,coalesced", [((1024,), False),
-                                             ((256, 4), True)])
-def test_ild_coalesces_row_tables_only(shape, coalesced):
-    """A 1-D ILD lowers to one plain gather (sorting and deduplicating
-    its lanes would only add a sort and an inverse scatter); a row table
-    keeps the sorted, deduplicated fetch. Both read A[B]."""
-    prog = isa.AccessProgram((isa.SLD("i32", "B", "b"),
-                              isa.ILD("f32", "A", "a", "b")), tile_size=64)
-    a = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
-    b = (np.arange(64, dtype=np.int32) * 37) % shape[0]
-    env = {"A": jnp.asarray(a), "B": jnp.asarray(b)}
-    eng = Engine(tile_size=64)
-    jaxpr = str(jax.make_jaxpr(lambda e: eng.run(prog, e, {}, {}))(env))
-    assert bool(re.search(r"\bsort\[", jaxpr)) == coalesced
-    _, spd = eng.run(prog, env, {}, {})
-    np.testing.assert_array_equal(np.asarray(spd["a"]), a[b])
