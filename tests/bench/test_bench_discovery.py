@@ -206,8 +206,7 @@ def test_a_configuration_must_name_a_known_kind(tiny_root, kind):
     if kind:
         cfg["kind"] = kind
     path.write_text(json.dumps(cfg))
-    with pytest.raises(ValueError,
-                       match=r"known kinds: \['bfs', 'keyed'\]"):
+    with pytest.raises(ValueError, match=r"known kinds: \['keyed'\]"):
         harness.find_cell("a", tiny_root)
 
 
